@@ -43,7 +43,8 @@ def _strategy_rows(model, task, quick: bool) -> List[Dict]:
     ]
     rows: List[Dict] = []
     if jax.device_count() >= n:
-        mesh = jax.make_mesh((n,), ("pod",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((n,), ("pod",))
         setups.append(("shardmap", ShardMapCompressed(topk_cfg, mesh), batch,
                        "on"))
     else:
@@ -63,7 +64,8 @@ def _strategy_rows(model, task, quick: bool) -> List[Dict]:
         state = strategy.init_state(model, tc, jax.random.key(0), opt_init,
                                     bt)
         comm = strategy.comm_bytes(model, state, bt)
-        fn = bundle.jitted(variant)
+        # undonated: the timing loop calls the step on the same state
+        fn = jax.jit(bundle.variants[variant])
         _, us = timed(lambda f=fn, st=state, bb=bt: f(st, bb), warmup=1,
                       iters=2 if quick else 5)
         rows.append({"name": f"throughput/strategy_{name}",

@@ -29,7 +29,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import CodistConfig
 
 PyTree = Any
@@ -231,7 +230,7 @@ def _compress_stacked(cfg: CodistConfig, targets: jax.Array) -> Dict:
 
         out_specs = jax.tree.map(lambda _: P("pod"),
                                  jax.eval_shape(comp, targets))
-        return compat.shard_map(comp, mesh=mesh, in_specs=P("pod"),
+        return jax.shard_map(comp, mesh=mesh, in_specs=P("pod"),
                              out_specs=out_specs, axis_names={"pod"},
                              check_vma=False)(targets)
     return compress_targets(cfg, targets)
@@ -269,7 +268,7 @@ def _podlocal_codist_terms(cfg: CodistConfig, mesh,
         dist = dist / max(1, n - 1)
         return jnp.stack([task, dist])[None]        # (1, 2) pod-sharded
 
-    rows = compat.shard_map(
+    rows = jax.shard_map(
         per_pod, mesh=mesh,
         in_specs=(P("pod"), P("pod"), P("pod"), P()),
         out_specs=P("pod", None),

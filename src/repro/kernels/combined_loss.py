@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fused_ce import NEG, pl_scratch
+from repro.kernels.fused_ce import NEG, col, tok_out, tok_scratch
 from repro.kernels.fused_ce import ce_accumulate as _ce_accumulate
 from repro.kernels.fused_ce import ce_grad_term as _ce_grad_term
 from repro.kernels.fused_ce import tile_spec as _tile_spec
@@ -63,7 +63,7 @@ def _combined_mse_kernel(labels_ref, s_logits_ref, t_logits_ref,
     # exact — mask them out rather than relying on a zero diff
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_v
     d = jnp.where(cols < v_real, x - t, 0.0)
-    acc_ref[...] = acc_ref[...] + jnp.sum(d * d, axis=-1)
+    acc_ref[...] = acc_ref[...] + jnp.sum(d * d, axis=-1, keepdims=True)
 
     @pl.when(j == n_v - 1)
     def _fin():
@@ -94,11 +94,12 @@ def _combined_kl_kernel(labels_ref, s_logits_ref, t_logits_ref,
                    block_v=block_v, v_real=v_real)
     # target-side online logsumexp + rescaled cross term
     mt_prev = mt_ref[...]
-    mt_new = jnp.maximum(mt_prev, jnp.max(lt, axis=-1))
+    mt_new = jnp.maximum(mt_prev, jnp.max(lt, axis=-1, keepdims=True))
     alpha_t = jnp.exp(mt_prev - mt_new)
-    w = jnp.exp(lt - mt_new[:, None])
-    st_ref[...] = st_ref[...] * alpha_t + jnp.sum(w, axis=-1)
-    u_ref[...] = u_ref[...] * alpha_t + jnp.sum(w * (lt - x), axis=-1)
+    w = jnp.exp(lt - mt_new)
+    st_ref[...] = st_ref[...] * alpha_t + jnp.sum(w, axis=-1, keepdims=True)
+    u_ref[...] = u_ref[...] * alpha_t + jnp.sum(w * (lt - x), axis=-1,
+                                                keepdims=True)
     mt_ref[...] = mt_new
 
     @pl.when(j == n_v - 1)
@@ -130,7 +131,6 @@ def fused_ce_distill_parts(logits: jax.Array, target_logits: jax.Array,
     v_real = v_real or v
     assert t % block_t == 0 and v % block_v == 0, (t, v, block_t, block_v)
     n_t, n_v = t // block_t, v // block_v
-    sds = jax.ShapeDtypeStruct((t,), jnp.float32)
     if mode == "mse":
         kernel = functools.partial(_combined_mse_kernel, block_v=block_v,
                                    n_v=n_v, v_real=v_real)
@@ -147,10 +147,11 @@ def fused_ce_distill_parts(logits: jax.Array, target_logits: jax.Array,
         in_specs=[_tok_spec(block_t), _tile_spec(block_t, block_v),
                   _tile_spec(block_t, block_v)],
         out_specs=[_tok_spec(block_t) for _ in range(n_out)],
-        out_shape=[sds] * n_out,
-        scratch_shapes=[pl_scratch((block_t,)) for _ in range(n_scratch)],
+        out_shape=tok_out(t, n_out),
+        scratch_shapes=[tok_scratch(block_t) for _ in range(n_scratch)],
         interpret=interpret,
-    )(labels, logits, target_logits)
+    )(col(labels), logits, target_logits)
+    outs = tuple(o[:, 0] for o in outs)
     return outs[:3], outs[3:]
 
 
@@ -170,7 +171,7 @@ def _combined_mse_grad_kernel(labels_ref, logzs_ref, gn_ref, gs_ref, gd_ref,
     # round-trip makes x-t nonzero (or inf for narrow dtypes) on padded cols
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_v
     d = jnp.where(cols < v_real, x - t, 0.0)
-    dd = gd_ref[...][:, None] * (2.0 / v_real) * d
+    dd = gd_ref[...] * (2.0 / v_real) * d
     ds_ref[...] = (ce + dd).astype(ds_ref.dtype)
     dt_ref[...] = (-dd).astype(dt_ref.dtype)
 
@@ -183,11 +184,10 @@ def _combined_kl_grad_kernel(labels_ref, logzs_ref, logzt_ref, e_ref, gn_ref,
     lt = t_logits_ref[...].astype(jnp.float32)
     ce, q = _ce_grad_term(x, labels_ref[...], logzs_ref[...], gn_ref[...],
                           gs_ref[...], j, block_v=block_v, v_real=v_real)
-    p = jnp.exp(lt - logzt_ref[...][:, None])
-    gd = gd_ref[...][:, None]
+    p = jnp.exp(lt - logzt_ref[...])
+    gd = gd_ref[...]
     ds_ref[...] = (ce + gd * (q - p)).astype(ds_ref.dtype)
-    dt_ref[...] = (gd * p * ((lt - x) - e_ref[...][:, None])).astype(
-        dt_ref.dtype)
+    dt_ref[...] = (gd * p * ((lt - x) - e_ref[...])).astype(dt_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "block_t", "block_v",
@@ -221,4 +221,5 @@ def fused_ce_distill_grad(logits: jax.Array, target_logits: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((t, v), logits.dtype),
                    jax.ShapeDtypeStruct((t, v), target_logits.dtype)],
         interpret=interpret,
-    )(labels, *residuals, g_nll, g_smooth, g_dist, logits, target_logits)
+    )(*map(col, (labels, *residuals, g_nll, g_smooth, g_dist)), logits,
+      target_logits)

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fused_ce import pl_scratch
+from repro.kernels.fused_ce import col, tok_out, tok_scratch
 from repro.kernels.fused_ce import tile_spec as _tile_spec
 from repro.kernels.fused_ce import tok_spec as _tok_spec
 
@@ -49,7 +49,7 @@ def _mse_kernel(a_ref, b_ref, out_ref, acc_ref, *, n_v: int, v_total: int):
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     d = a - b
-    acc_ref[...] = acc_ref[...] + jnp.sum(d * d, axis=-1)
+    acc_ref[...] = acc_ref[...] + jnp.sum(d * d, axis=-1, keepdims=True)
 
     @pl.when(j == n_v - 1)
     def _fin():
@@ -64,18 +64,19 @@ def _kl_accumulate(s_logits_ref, t_logits_ref, mt_ref, st_ref, ms_ref, ss_ref,
 
     # target-side online logsumexp + rescaled cross term U = sum e^{lt-Mt}(lt-ls)
     mt_prev = mt_ref[...]
-    mt_new = jnp.maximum(mt_prev, jnp.max(lt, axis=-1))
+    mt_new = jnp.maximum(mt_prev, jnp.max(lt, axis=-1, keepdims=True))
     alpha_t = jnp.exp(mt_prev - mt_new)
-    w = jnp.exp(lt - mt_new[:, None])
-    st_ref[...] = st_ref[...] * alpha_t + jnp.sum(w, axis=-1)
-    u_ref[...] = u_ref[...] * alpha_t + jnp.sum(w * (lt - ls), axis=-1)
+    w = jnp.exp(lt - mt_new)
+    st_ref[...] = st_ref[...] * alpha_t + jnp.sum(w, axis=-1, keepdims=True)
+    u_ref[...] = u_ref[...] * alpha_t + jnp.sum(w * (lt - ls), axis=-1,
+                                                keepdims=True)
     mt_ref[...] = mt_new
 
     # student-side online logsumexp
     ms_prev = ms_ref[...]
-    ms_new = jnp.maximum(ms_prev, jnp.max(ls, axis=-1))
+    ms_new = jnp.maximum(ms_prev, jnp.max(ls, axis=-1, keepdims=True))
     ss_ref[...] = ss_ref[...] * jnp.exp(ms_prev - ms_new) + jnp.sum(
-        jnp.exp(ls - ms_new[:, None]), axis=-1)
+        jnp.exp(ls - ms_new), axis=-1, keepdims=True)
     ms_ref[...] = ms_new
 
 
@@ -149,10 +150,10 @@ def fused_distill_loss(logits: jax.Array, target_logits: jax.Array,
     n_t, n_v = t // block_t, v // block_v
     if mode == "mse":
         kernel = functools.partial(_mse_kernel, n_v=n_v, v_total=v_total or v)
-        scratch = [pl_scratch((block_t,))]
+        scratch = [tok_scratch(block_t)]
     elif mode == "kl":
         kernel = functools.partial(_kl_kernel, n_v=n_v)
-        scratch = [pl_scratch((block_t,)) for _ in range(5)]
+        scratch = [tok_scratch(block_t) for _ in range(5)]
     else:
         raise ValueError(mode)
     return pl.pallas_call(
@@ -160,10 +161,10 @@ def fused_distill_loss(logits: jax.Array, target_logits: jax.Array,
         grid=(n_t, n_v),
         in_specs=[_tile_spec(block_t, block_v), _tile_spec(block_t, block_v)],
         out_specs=_tok_spec(block_t),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
+        out_shape=tok_out(t, 1)[0],
         scratch_shapes=scratch,
         interpret=interpret,
-    )(logits, target_logits)
+    )(logits, target_logits)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_v",
@@ -177,15 +178,16 @@ def fused_distill_kl_parts(logits: jax.Array, target_logits: jax.Array,
     assert t % block_t == 0 and v % block_v == 0, (t, v, block_t, block_v)
     n_t, n_v = t // block_t, v // block_v
     kernel = functools.partial(_kl_parts_kernel, n_v=n_v)
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
         grid=(n_t, n_v),
         in_specs=[_tile_spec(block_t, block_v), _tile_spec(block_t, block_v)],
         out_specs=[_tok_spec(block_t) for _ in range(4)],
-        out_shape=[jax.ShapeDtypeStruct((t,), jnp.float32)] * 4,
-        scratch_shapes=[pl_scratch((block_t,)) for _ in range(5)],
+        out_shape=tok_out(t, 4),
+        scratch_shapes=[tok_scratch(block_t) for _ in range(5)],
         interpret=interpret,
     )(logits, target_logits)
+    return tuple(o[:, 0] for o in outs)
 
 
 # ----------------------------------------------------------------------------
@@ -195,7 +197,7 @@ def fused_distill_kl_parts(logits: jax.Array, target_logits: jax.Array,
 def _mse_grad_kernel(a_ref, b_ref, g_ref, da_ref, db_ref, *, v_total: int):
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
-    da = g_ref[...][:, None] * (2.0 / v_total) * (a - b)
+    da = g_ref[...] * (2.0 / v_total) * (a - b)
     da_ref[...] = da.astype(da_ref.dtype)
     db_ref[...] = (-da).astype(db_ref.dtype)
 
@@ -204,12 +206,11 @@ def _kl_grad_kernel(s_ref, t_ref, logzs_ref, logzt_ref, e_ref, g_ref,
                     ds_ref, dt_ref):
     ls = s_ref[...].astype(jnp.float32)
     lt = t_ref[...].astype(jnp.float32)
-    q = jnp.exp(ls - logzs_ref[...][:, None])        # softmax(student)
-    p = jnp.exp(lt - logzt_ref[...][:, None])        # softmax(target)
-    g = g_ref[...][:, None]
+    q = jnp.exp(ls - logzs_ref[...])                 # softmax(student)
+    p = jnp.exp(lt - logzt_ref[...])                 # softmax(target)
+    g = g_ref[...]
     ds_ref[...] = (g * (q - p)).astype(ds_ref.dtype)
-    dt_ref[...] = (g * p * ((lt - ls) - e_ref[...][:, None])).astype(
-        dt_ref.dtype)
+    dt_ref[...] = (g * p * ((lt - ls) - e_ref[...])).astype(dt_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_v", "v_total",
@@ -232,7 +233,7 @@ def fused_distill_mse_grad(logits: jax.Array, target_logits: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((t, v), logits.dtype),
                    jax.ShapeDtypeStruct((t, v), target_logits.dtype)],
         interpret=interpret,
-    )(logits, target_logits, g)
+    )(logits, target_logits, col(g))
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_v",
@@ -259,4 +260,4 @@ def fused_distill_kl_grad(logits: jax.Array, target_logits: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((t, v), logits.dtype),
                    jax.ShapeDtypeStruct((t, v), target_logits.dtype)],
         interpret=interpret,
-    )(logits, target_logits, logzs, logzt, e, g)
+    )(logits, target_logits, col(logzs), col(logzt), col(e), col(g))

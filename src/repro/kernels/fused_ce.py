@@ -6,13 +6,12 @@ intermediates at full width; these kernels stream vocab TILES through VMEM,
 maintaining online per-token accumulators — one pass over the logits per
 direction, no (T, V) fp32 temporary, MXU-free (pure VPU reduction).
 
-Forward kernels
-  * ``_ce_kernel``        — plain NLL (the original seed kernel, kept for the
-    forward-only ``fused_cross_entropy`` entry point);
+Forward kernel
   * ``_ce_parts_kernel``  — NLL *and* the label-smoothing term
     ``logZ - mean_v(x)`` plus the ``logZ`` residual, so the custom-VJP wrapper
     in ``ops.py`` can compose arbitrary smoothing outside the kernel and the
-    backward never recomputes the normalizer.
+    backward never recomputes the normalizer. The forward-only
+    ``fused_cross_entropy`` keeps its NLL.
 
 Backward kernel
   * ``_ce_grad_kernel``   — ``dL/dx = (g_nll + g_smooth) * softmax(x)
@@ -37,13 +36,29 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -1e30
 
 
-def pl_scratch(shape, dtype=jnp.float32):
-    return pltpu.VMEM(shape, dtype)
-
-
 def tok_spec(block_t):
-    """BlockSpec for a per-token (T,) operand on a (n_t, n_v) grid."""
-    return pl.BlockSpec((block_t,), lambda i, j: (i,))
+    """BlockSpec for a per-token operand on a (n_t, n_v) grid.
+
+    Per-token operands travel as (T, 1) columns: Mosaic refuses 1-D (T,)
+    blocks (XLA's 1-D tiling does not match the kernel's), while a (block_t,
+    1) block obeys the TPU tiling rule (last dim equal to the array's) and
+    broadcasts against (block_t, block_v) tiles without a relayout."""
+    return pl.BlockSpec((block_t, 1), lambda i, j: (i, 0))
+
+
+def col(x: jax.Array) -> jax.Array:
+    """(T,) -> (T, 1) per-token column for ``tok_spec`` operands."""
+    return x.reshape(-1, 1)
+
+
+def tok_scratch(block_t: int):
+    """fp32 VMEM scratch for one per-token accumulator column."""
+    return pltpu.VMEM((block_t, 1), jnp.float32)
+
+
+def tok_out(t: int, n: int):
+    """``n`` per-token fp32 (T, 1) kernel outputs."""
+    return [jax.ShapeDtypeStruct((t, 1), jnp.float32)] * n
 
 
 def tile_spec(block_t, block_v):
@@ -55,93 +70,31 @@ def ce_accumulate(x, labels, j, m_ref, s_ref, t_ref, x_ref, *,
                   block_v: int, v_real: int):
     """One vocab tile of the streaming CE state: online (max, sumexp) plus
     the true-logit and real-column logit-sum accumulators. Shared between the
-    standalone CE kernel and the combined CE+distill kernel."""
+    CE kernel and the combined CE+distill kernel."""
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(x, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(x, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    s_ref[...] = s_ref[...] * alpha + jnp.sum(jnp.exp(x - m_new[:, None]),
-                                              axis=-1)
+    s_ref[...] = s_ref[...] * alpha + jnp.sum(jnp.exp(x - m_new), axis=-1,
+                                              keepdims=True)
     m_ref[...] = m_new
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_v
-    hit = cols == labels[:, None]
-    t_ref[...] = t_ref[...] + jnp.sum(jnp.where(hit, x, 0.0), axis=-1)
+    hit = cols == labels
+    t_ref[...] = t_ref[...] + jnp.sum(jnp.where(hit, x, 0.0), axis=-1,
+                                      keepdims=True)
     # sum of REAL logits only (padded cols hold -1e30, excluded by v_real)
     x_ref[...] = x_ref[...] + jnp.sum(jnp.where(cols < v_real, x, 0.0),
-                                      axis=-1)
+                                      axis=-1, keepdims=True)
 
 
 def ce_grad_term(x, labels, logz, gn, gs, j, *, block_v: int, v_real: int):
     """(dL/dx tile, softmax tile) for g_nll*nll + g_smooth*smooth, from the
-    saved logZ residual: (gn+gs)*softmax - gn*onehot - gs*valid/V."""
-    p = jnp.exp(x - logz[:, None])
+    saved logZ residual: (gn+gs)*softmax - gn*onehot - gs*valid/V. The
+    per-token operands are (block_t, 1) columns."""
+    p = jnp.exp(x - logz)
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_v
-    onehot = (cols == labels[:, None]).astype(jnp.float32)
+    onehot = (cols == labels).astype(jnp.float32)
     valid = (cols < v_real).astype(jnp.float32)
-    return ((gn + gs)[:, None] * p - gn[:, None] * onehot
-            - gs[:, None] * (valid / v_real)), p
-
-
-def _ce_kernel(labels_ref, logits_ref, loss_ref, m_ref, s_ref, t_ref, *,
-               block_v: int, n_v: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        s_ref[...] = jnp.zeros_like(s_ref)
-        t_ref[...] = jnp.zeros_like(t_ref)
-
-    x = logits_ref[...].astype(jnp.float32)          # (block_t, block_v)
-    labels = labels_ref[...]                         # (block_t,)
-
-    # online logsumexp update
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(x, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    s_ref[...] = s_ref[...] * alpha + jnp.sum(jnp.exp(x - m_new[:, None]),
-                                              axis=-1)
-    m_ref[...] = m_new
-
-    # accumulate the true logit if the label falls in this vocab tile
-    base = j * block_v
-    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + base
-    hit = cols == labels[:, None]
-    t_ref[...] = t_ref[...] + jnp.sum(jnp.where(hit, x, 0.0), axis=-1)
-
-    @pl.when(j == n_v - 1)
-    def _fin():
-        loss_ref[...] = m_ref[...] + jnp.log(s_ref[...]) - t_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("block_t", "block_v", "interpret"))
-def fused_cross_entropy(logits: jax.Array, labels: jax.Array,
-                        block_t: int = 256, block_v: int = 512,
-                        interpret: bool = False) -> jax.Array:
-    """Per-token CE. logits (T, V), labels (T,) int32 -> (T,) fp32.
-
-    T % block_t == 0 and V % block_v == 0 (callers pad; configs already pad
-    vocab to a multiple of 256).
-    """
-    t, v = logits.shape
-    assert t % block_t == 0 and v % block_v == 0, (t, v, block_t, block_v)
-    n_t, n_v = t // block_t, v // block_v
-    kernel = functools.partial(_ce_kernel, block_v=block_v, n_v=n_v)
-    return pl.pallas_call(
-        kernel,
-        grid=(n_t, n_v),
-        in_specs=[
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
-            pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((block_t,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
-        scratch_shapes=[
-            pl_scratch((block_t,)),
-            pl_scratch((block_t,)),
-            pl_scratch((block_t,)),
-        ],
-        interpret=interpret,
-    )(labels, logits)
+    return (gn + gs) * p - gn * onehot - gs * (valid / v_real), p
 
 
 # ----------------------------------------------------------------------------
@@ -190,15 +143,29 @@ def fused_cross_entropy_parts(logits: jax.Array, labels: jax.Array,
     n_t, n_v = t // block_t, v // block_v
     kernel = functools.partial(_ce_parts_kernel, block_v=block_v, n_v=n_v,
                                v_real=v_real)
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
         grid=(n_t, n_v),
         in_specs=[tok_spec(block_t), tile_spec(block_t, block_v)],
         out_specs=[tok_spec(block_t) for _ in range(3)],
-        out_shape=[jax.ShapeDtypeStruct((t,), jnp.float32)] * 3,
-        scratch_shapes=[pl_scratch((block_t,)) for _ in range(4)],
+        out_shape=tok_out(t, 3),
+        scratch_shapes=[tok_scratch(block_t) for _ in range(4)],
         interpret=interpret,
-    )(labels, logits)
+    )(col(labels), logits)
+    return tuple(o[:, 0] for o in outs)
+
+
+def fused_cross_entropy(logits: jax.Array, labels: jax.Array,
+                        block_t: int = 256, block_v: int = 512,
+                        interpret: bool = False) -> jax.Array:
+    """Per-token CE. logits (T, V), labels (T,) int32 -> (T,) fp32.
+
+    T % block_t == 0 and V % block_v == 0 (callers pad; configs already pad
+    vocab to a multiple of 256).
+    """
+    return fused_cross_entropy_parts(logits, labels, block_t=block_t,
+                                     block_v=block_v,
+                                     interpret=interpret)[0]
 
 
 # ----------------------------------------------------------------------------
@@ -238,4 +205,4 @@ def fused_cross_entropy_grad(logits: jax.Array, labels: jax.Array,
         out_specs=tile_spec(block_t, block_v),
         out_shape=jax.ShapeDtypeStruct((t, v), logits.dtype),
         interpret=interpret,
-    )(labels, logz, g_nll, g_smooth, logits)
+    )(col(labels), col(logz), col(g_nll), col(g_smooth), logits)

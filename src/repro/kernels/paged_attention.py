@@ -7,21 +7,23 @@ passes over every slot's context: ``paged_gather`` materializes a dense
 all again. This kernel consumes the block table directly, so that gather
 temporary never exists and each live KV block is read exactly once:
 
-  grid (S, KVh, MB), KV blocks innermost. Program (s, k, m) DMAs pool
-  block ``table[s, m]`` (scalar-prefetched, like ``paged_cache`` — dead
-  entries alias the all-zero null block 0) and folds it into the canonical
-  online-softmax state (running max ``m``, denominator ``l``, accumulator
-  ``acc`` — the same machinery as ``kernels/flash_attention``), carried in
-  VMEM scratch across the innermost grid steps. Blocks at or past
+  grid (S, MB), KV blocks innermost. Program (s, m) DMAs pool block
+  ``table[s, m]`` for every KV head (scalar-prefetched, like
+  ``paged_cache`` — dead entries alias the all-zero null block 0) and
+  folds it into the canonical online-softmax state (running max ``m``,
+  denominator ``l``, accumulator ``acc`` — the same machinery as
+  ``kernels/flash_attention``), carried in VMEM scratch across the
+  innermost grid steps. Blocks at or past
   ``n_live[s]`` are skipped entirely (``pl.when``), positions past the
   slot's own length are masked to ``NEG`` in-tile (per-slot vector
   positions: every slot decodes at its OWN absolute position), and GQA maps
-  the ``G = H // KVh`` query heads of group ``k`` onto KV head ``k`` via
-  the BlockSpec index maps.
+  the ``G = H // KVh`` query heads of group ``k`` onto KV head ``k`` as the
+  batch axis of head-major batched dots.
 
 Quantized pools (int8 / fp8, see ``paged_cache.quantize_rows``) carry one
 fp32 scale per stored token row alongside the pool; the kernel dequantizes
-inside the inner loop (``k * scale[row]`` on the VMEM-resident tile), so
+inside the inner loop (row ``r``'s K scale multiplies its score, its V
+scale its softmax weight — the same products as dequantizing the tile), so
 quantization shrinks HBM traffic without a dequantized copy ever hitting
 HBM.
 
@@ -62,7 +64,7 @@ def _decode_kernel_quant(table_ref, len_ref, nlive_ref, q_ref, k_ref, v_ref,
 def _decode_body(ks_ref, vs_ref, table_ref, len_ref, nlive_ref, q_ref, k_ref,
                  v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                  scale: float, block_size: int, n_m: int):
-    si, mi = pl.program_id(0), pl.program_id(2)
+    si, mi = pl.program_id(0), pl.program_id(1)
 
     @pl.when(mi == 0)
     def _init():
@@ -72,29 +74,33 @@ def _decode_body(ks_ref, vs_ref, table_ref, len_ref, nlive_ref, q_ref, k_ref,
 
     @pl.when(mi < nlive_ref[si])
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (BS, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if ks_ref is not None:                            # dequant in-loop
-            k = k * ks_ref[...].T                         # (BS, 1) scales
-            v = v * vs_ref[...].T
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (G, BS)
-        pos = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        q = q_ref[0].astype(jnp.float32) * scale          # (KVh, G, hd)
+        # (BS, KVh, hd) block -> head-major (KVh, BS, hd) for the batched dots
+        k = jnp.swapaxes(k_ref[0].astype(jnp.float32), 0, 1)
+        v = jnp.swapaxes(v_ref[0].astype(jnp.float32), 0, 1)
+        s = jnp.einsum("kgd,ktd->kgt", q, k,
+                       preferred_element_type=jnp.float32)  # (KVh, G, BS)
+        if ks_ref is not None:
+            # dequant in-loop: a per-row scale on K scales that row's score,
+            # on V the row's softmax weight — (1, BS) lane vectors
+            s = s * ks_ref[0]
+        pos = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
                + mi * block_size)
         s = jnp.where(pos <= len_ref[si], s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]                                # (KVh, G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = p * vs_ref[0] if vs_ref is not None else p
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "kgt,ktd->kgd", pv, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(mi == n_m - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -113,6 +119,10 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
     (valid keys are positions <= lengths[s]); k_scale / v_scale (NB, BS)
     fp32 per-row dequant scales for quantized pools (both or neither).
     Returns (S, H, hd) attention outputs in q's dtype.
+
+    Each program DMAs one whole pool block, every KV head at once: a
+    one-head ``(1, BS, 1, hd)`` block would break the TPU tiling rule
+    (the last two block dims must be multiples of (8, 128) or whole).
     """
     if interpret is None:
         from repro.kernels.ops import auto_interpret
@@ -126,19 +136,19 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
     assert quantized == (v_scale is not None), "pass both scales or neither"
     n_live = (lengths.astype(jnp.int32) + bs) // bs   # blocks incl. new token
 
-    pool_spec = pl.BlockSpec((1, bs, 1, hd),
-                             lambda si, ki, mi, t, le, nl: (t[si, mi], 0, ki, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda si, ki, mi, t, le, nl: (si, ki, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
+    pool_spec = pl.BlockSpec((1, bs, kvh, hd),
+                             lambda si, mi, t, le, nl: (t[si, mi], 0, 0, 0))
+    slot_spec = pl.BlockSpec((1, kvh, g, hd),
+                             lambda si, mi, t, le, nl: (si, 0, 0, 0))
+    in_specs = [slot_spec, pool_spec, pool_spec]
     operands = [q.reshape(s, kvh, g, hd), k_pool, v_pool]
     if quantized:
+        # (NB, 1, BS): the row scales of one block as a (1, BS) lane vector
         scale_spec = pl.BlockSpec(
-            (1, bs), lambda si, ki, mi, t, le, nl: (t[si, mi], 0))
+            (1, 1, bs), lambda si, mi, t, le, nl: (t[si, mi], 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        operands += [k_scale.astype(jnp.float32).reshape(nb, 1, bs),
+                     v_scale.astype(jnp.float32).reshape(nb, 1, bs)]
         kernel = _decode_kernel_quant
     else:
         kernel = _decode_kernel
@@ -146,14 +156,13 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
         functools.partial(kernel, scale=hd ** -0.5, block_size=bs, n_m=mb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(s, kvh, mb),
+            grid=(s, mb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, g, hd),
-                                   lambda si, ki, mi, t, le, nl: (si, ki, 0, 0)),
+            out_specs=slot_spec,
             scratch_shapes=[
-                pltpu.VMEM((g,), jnp.float32),
-                pltpu.VMEM((g,), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
+                pltpu.VMEM((kvh, g, 1), jnp.float32),
+                pltpu.VMEM((kvh, g, 1), jnp.float32),
+                pltpu.VMEM((kvh, g, hd), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s, kvh, g, hd), q.dtype),

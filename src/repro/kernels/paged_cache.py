@@ -103,8 +103,7 @@ def _scatter_kernel(wslot_ref, woff_ref, new_ref, pool_ref, out_ref, *,
     b = pl.program_id(0)
     w = wslot_ref[b]
     off = woff_ref[b]
-    src = pl.load(new_ref, (pl.dslice(jnp.maximum(w, 0), 1),
-                            slice(None), slice(None)))      # (1, KV, hd)
+    src = new_ref[pl.ds(jnp.maximum(w, 0), 1), :, :]      # (1, KV, hd)
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_size, 1, 1), 0)
     mask = (rows == off) & (w >= 0)
     out_ref[0] = jnp.where(mask, src, pool_ref[0])
@@ -200,8 +199,7 @@ def _scatter_quant_kernel(wslot_ref, woff_ref, new_ref, pool_ref, sc_ref,
     b = pl.program_id(0)
     w = wslot_ref[b]
     off = woff_ref[b]
-    src = pl.load(new_ref, (pl.dslice(jnp.maximum(w, 0), 1),
-                            slice(None), slice(None)))      # (1, KV, hd)
+    src = new_ref[pl.ds(jnp.maximum(w, 0), 1), :, :]      # (1, KV, hd)
     src = src.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(src))
     scale = absmax / qmax
@@ -210,7 +208,7 @@ def _scatter_quant_kernel(wslot_ref, woff_ref, new_ref, pool_ref, sc_ref,
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_size, 1, 1), 0)
     mask = (rows == off) & (w >= 0)
     out_ref[0] = jnp.where(mask, qrow, pool_ref[0])
-    rows2 = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+    rows2 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_size), 2)
     mask2 = (rows2 == off) & (w >= 0)
     osc_ref[...] = jnp.where(mask2, scale, sc_ref[...])
 
@@ -222,14 +220,16 @@ def paged_scatter_quant(pool: jax.Array, scales: jax.Array, new: jax.Array,
     """``paged_scatter`` fused with row quantization: the appended fp32 KV
     row is absmax-scaled and stored quantized, its scale written into the
     ``(NB, BS)`` per-row scale array. Returns ``(pool, scales)``.
-    Same writer-map contract as ``paged_scatter``."""
+    Same writer-map contract as ``paged_scatter``. The scales travel as
+    ``(NB, 1, BS)`` so each block's row of scales is a whole (1, BS) tile
+    (a ``(1, BS)`` block of ``(NB, BS)`` breaks the TPU tiling rule)."""
     if interpret is None:
         from repro.kernels.ops import auto_interpret
         interpret = auto_interpret()
     nb, bs, kv, hd = pool.shape
     s = new.shape[0]
     qmax = _QMAX[jnp.dtype(pool.dtype).name]
-    return pl.pallas_call(
+    out, out_scales = pl.pallas_call(
         functools.partial(_scatter_quant_kernel, block_size=bs, qmax=qmax,
                           out_dtype=pool.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -238,18 +238,20 @@ def paged_scatter_quant(pool: jax.Array, scales: jax.Array, new: jax.Array,
             in_specs=[
                 pl.BlockSpec((s, kv, hd), lambda b, ws, wo: (0, 0, 0)),
                 pl.BlockSpec((1, bs, kv, hd), lambda b, ws, wo: (b, 0, 0, 0)),
-                pl.BlockSpec((1, bs), lambda b, ws, wo: (b, 0)),
+                pl.BlockSpec((1, 1, bs), lambda b, ws, wo: (b, 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, bs, kv, hd), lambda b, ws, wo: (b, 0, 0, 0)),
-                pl.BlockSpec((1, bs), lambda b, ws, wo: (b, 0)),
+                pl.BlockSpec((1, 1, bs), lambda b, ws, wo: (b, 0, 0)),
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-                   jax.ShapeDtypeStruct(scales.shape, jnp.float32)],
+                   jax.ShapeDtypeStruct((nb, 1, bs), jnp.float32)],
         interpret=interpret,
     )(write_slot.astype(jnp.int32), write_off.astype(jnp.int32),
-      new.astype(jnp.float32), pool, scales.astype(jnp.float32))
+      new.astype(jnp.float32), pool,
+      scales.astype(jnp.float32).reshape(nb, 1, bs))
+    return out, out_scales.reshape(nb, bs)
 
 
 def paged_scatter_quant_ref(pool: jax.Array, scales: jax.Array,

@@ -32,7 +32,7 @@ from repro.configs import (ASSIGNED_ARCHS, INPUT_SHAPES, CodistConfig,  # noqa: 
 from repro.launch import sharding as sh  # noqa: E402
 from repro.launch import specs as sp     # noqa: E402
 from repro.launch.hlo_analysis import parse_collectives  # noqa: E402
-from repro.launch.mesh import make_production_mesh, set_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.roofline import build_report  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.models import sharding_hints as hints  # noqa: E402
@@ -47,15 +47,6 @@ SDS = jax.ShapeDtypeStruct
 # sub-quadratic carve-in); whisper skips it entirely (see DESIGN.md).
 SLIDING_WINDOW_FOR_LONG = 8192
 SKIP = {("whisper-tiny", "long_500k")}
-
-
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions (older returns a list of
-    per-program dicts, newer a single dict)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 
 def dryrun_config(arch: str):
@@ -137,8 +128,8 @@ def _train_lowering(model, cfg, shape, mesh, mode: str, codist_n: int,
     batch_axes = ("data",) if stacked else (
         ("pod", "data") if multi else ("data",))
     tp_size = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
-    with set_mesh(mesh), hints.activation_sharding(batch_axes, "model",
-                                                   tp_size, mesh):
+    with jax.set_mesh(mesh), hints.activation_sharding(
+            batch_axes, "model", tp_size, mesh):
         lowered = jax.jit(step, in_shardings=(state_sh, batch_sh)).lower(
             state_sds, batch_sds)
     return lowered
@@ -154,7 +145,7 @@ def _prefill_lowering(model, cfg, shape, mesh):
     batch_sds = sp.prefill_batch_specs(cfg, shape)
     params_sh = sh.state_shardings(params_sds, mesh)
     batch_sh = sh.batch_shardings(batch_sds, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(prefill_step,
                           in_shardings=(params_sh, batch_sh)).lower(
             params_sds, batch_sds)
@@ -193,7 +184,7 @@ def _decode_lowering(model, cfg, shape, mesh, variant: Optional[Dict] = None):
     else:
         tok_sh = sh.batch_shardings(tok_sds, mesh)
     pos_sh = sh.replicated(mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(decode_step, in_shardings=(
             params_sh, cache_sh, tok_sh, pos_sh)).lower(
             params_sds, cache_sds, tok_sds, pos_sds)
@@ -214,7 +205,7 @@ def _lower_for(model, cfg, shape, mesh, mode: str, codist_n: int,
 
 
 def _extract_cost(compiled, multi_pod: bool, devices_per_pod: int = 256):
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = parse_collectives(compiled.as_text(),
                              devices_per_pod=devices_per_pod if multi_pod
                              else 0)
@@ -315,7 +306,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, mode: str = "auto",
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     try:
         mem = compiled.memory_analysis()
         mem_d = {

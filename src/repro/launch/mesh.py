@@ -15,47 +15,33 @@ is the prediction exchange — the paper's setup mapped onto TPU topology.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where available (jax >= 0.6); on older jax a ``Mesh`` is
-    itself a context manager with the same effect for pjit/shard_map.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def abstract_mesh(axis_sizes, axis_names):
-    """Device-free mesh for sharding-rule unit tests, across jax versions.
-
-    Newer jax: ``AbstractMesh(axis_sizes, axis_names)``; jax <= 0.4 takes a
-    single ``((name, size), ...)`` shape tuple.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings are propagated
+    by the partitioner (pjit / ``shard_map`` semantics). ``jax.make_mesh``
+    otherwise defaults to ``Explicit`` axes, under which closing over
+    sharded values in ``shard_map`` and duplicate-axis specs are errors."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_codist_mesh(n_models: int = 2, data: int = 8, model: int = 16):
     """Single-pod codistillation mesh: the pod's chips are partitioned into
     n_models groups (the paper's '8 GPUs per model on one server' analogue)."""
-    return jax.make_mesh((n_models, data, model), ("pod", "data", "model"))
+    return auto_mesh((n_models, data, model), ("pod", "data", "model"))
 
 
 def make_host_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
     """Tiny mesh for CI-scale distributed tests (8 forced host devices)."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def mesh_chips(mesh) -> int:

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_reduced, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime.clock import parse_faults
 from repro.serve import Engine, resolve_cache_dtype
@@ -40,7 +41,10 @@ from repro.serve.fleet import (POLICIES, SCENARIOS, ChaosConfig, FleetConfig,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=list_archs())
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the smoke-test cut of --arch; --no-reduced serves "
+                         "the published config")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--cache-dtype", default="auto",
                     help="KV/state cache dtype: auto (bf16 on TPU, fp32 in "
@@ -139,6 +143,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build_model(cfg)

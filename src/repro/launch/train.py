@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
         --mode codist --codist-n 2 --steps 200 --batch 8 --seq 128 \
-        --reduced --out results/train_run
+        --out results/train_run
 
 ``--mode`` maps one-to-one onto the engine's exchange strategies:
 
@@ -16,10 +16,11 @@
                                            seeded fault injection: --faults,
                                            --elastic, --staleness-bound
 
-On this container it runs REDUCED configs on CPU with synthetic data; on a
-real cluster the same entrypoint takes the full config (drop ``--reduced``)
-and the production mesh, where pjit shards the step exactly as the dry-run
-proved. ``codist-shardmap`` shard_maps over a "pod" mesh axis of size
+By default it runs the REDUCED config (``--reduced``) with synthetic data,
+which is what the CPU tests use; ``--no-reduced`` takes the published
+config. On one TPU v5e, qwen1.5-0.5b's 2-peer codist step fits with
+``--no-reduced --remat --optimizer sgdm --batch 4 --seq 512``.
+``codist-shardmap`` shard_maps over a "pod" mesh axis of size
 ``--codist-n``; on CPU that many host devices are forced (via XLA_FLAGS,
 before jax initializes — hence the deferred imports below).
 """
@@ -88,7 +89,13 @@ def main() -> None:
                     choices=["auto", "on", "off"],
                     help="custom-VJP Pallas loss kernels (auto: on for TPU; "
                          "'on' uses interpret mode on CPU — slow)")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the smoke-test cut of --arch (2 layers, narrow "
+                         "widths); --no-reduced runs the published config")
+    ap.add_argument("--remat", action="store_true",
+                    help="rematerialize each layer's activations in the "
+                         "backward pass (TrainConfig.remat)")
     ap.add_argument("--faults", default="",
                     help="codist-async fault spec, e.g. "
                          "'straggler=1*4@0.2,preempt=1@3+5,fail=1@30,"
@@ -131,6 +138,8 @@ def main() -> None:
                          "every fired alert or injected fault "
                          "(requires --alerts)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.rules and not args.alerts:
         ap.error("--rules requires --alerts")
@@ -177,7 +186,7 @@ def main() -> None:
         lr=args.lr, lr_schedule=args.lr_schedule, warmup_steps=args.warmup,
         total_steps=args.steps, weight_decay=args.weight_decay,
         weight_decay_schedule=(5e-4, 1e-5, 0.0) if args.wd_schedule else (),
-        optimizer=args.optimizer, seed=args.seed,
+        optimizer=args.optimizer, seed=args.seed, remat=args.remat,
         fused_losses={"auto": None, "on": True, "off": False}[
             args.fused_losses])
 
@@ -298,7 +307,8 @@ def main() -> None:
                 raise SystemExit(
                     f"codist-shardmap needs >= {args.codist_n} devices for "
                     f"the 'pod' axis; have {jax.device_count()}")
-            mesh = jax.make_mesh((args.codist_n,), ("pod",))
+            from repro.launch.mesh import auto_mesh
+            mesh = auto_mesh((args.codist_n,), ("pod",))
             strategy = ShardMapCompressed(codist, mesh)
         coordinated = codist.mode == "predictions"
 

@@ -37,17 +37,15 @@ def resolve_cache_dtype(name: Optional[str]):
 
     Quantized spellings (``int8``, ``fp8``/``float8_e4m3fn``) resolve to
     paged-pool storage dtypes — only the fleet engine serves them (the
-    dense ``Engine`` cache is never quantized); fp8 needs a jax with
-    ``jnp.float8_e4m3fn``.
+    dense ``Engine`` cache is never quantized).
     """
     if name is None or name == "auto":
         return default_cache_dtype()
     table = {"bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
              "fp32": jnp.float32, "float32": jnp.float32,
              "fp16": jnp.float16, "float16": jnp.float16,
-             "int8": jnp.int8}
-    if hasattr(jnp, "float8_e4m3fn"):
-        table["fp8"] = table["float8_e4m3fn"] = jnp.float8_e4m3fn
+             "int8": jnp.int8,
+             "fp8": jnp.float8_e4m3fn, "float8_e4m3fn": jnp.float8_e4m3fn}
     if name not in table:
         raise ValueError(f"unknown cache dtype {name!r}; "
                          f"valid names: auto, {', '.join(table)}")

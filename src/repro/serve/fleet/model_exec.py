@@ -125,7 +125,7 @@ def build_decode_step(model, fused_attention: Optional[bool] = None):
     ``kernels.paged_attention`` streaming-softmax kernel; False pins the
     jnp gather+dense-softmax oracle. All operands have step-invariant
     shapes, so the returned jit compiles exactly once per fleet engine and
-    every scheduler tick reuses it.
+    every scheduler tick reuses it. The ``kv`` argument is donated.
     """
     cfg = model.cfg
     kinds = _sub_kinds(cfg)
@@ -161,7 +161,9 @@ def build_decode_step(model, fused_attention: Optional[bool] = None):
         return logits[:, -1], kv, states
 
     _n_scan(cfg)           # called for effect: validates the scan layout early
-    return jax.jit(step)
+    # the KV pools (argument 1) are donated: every caller replaces its pools
+    # with the returned ones, so the step updates them in place in HBM
+    return jax.jit(step, donate_argnums=1)
 
 
 def _paged_attention_verify(p: Dict, x: jax.Array, kv: Dict[str, jax.Array],
@@ -295,4 +297,6 @@ def build_verify_step(model, k: int, fused_attention: Optional[bool] = None):
         return logits, kv, states
 
     _n_scan(cfg)           # called for effect: validates the scan layout early
-    return jax.jit(step)
+    # the KV pools (argument 1) are donated: every caller replaces its pools
+    # with the returned ones, so the step updates them in place in HBM
+    return jax.jit(step, donate_argnums=1)

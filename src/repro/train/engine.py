@@ -52,7 +52,6 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import CodistConfig, TrainConfig
 from repro.core import codistillation as cd
 from repro.core import comm_model as cm
@@ -666,7 +665,7 @@ class ShardMapCompressed(PredictionExchange):
             out = jnp.stack([total, task, dist, aux])
             return out[None]  # (1, 4): pod-sharded metrics row
 
-        per_pod_mapped = compat.shard_map(
+        per_pod_mapped = jax.shard_map(
             per_pod, mesh=mesh,
             in_specs=(lead_spec(params), lead_spec(batch)),
             out_specs=P("pod", None),
@@ -720,8 +719,11 @@ class StepBundle:
         self._jitted: Dict[str, Callable] = {}
 
     def jitted(self, variant: str = "on") -> Callable:
+        """The compiled variant. It donates the state (argument 0): the
+        caller must not read the state it passed in after the call."""
         if variant not in self._jitted:
-            self._jitted[variant] = jax.jit(self.variants[variant])
+            self._jitted[variant] = jax.jit(self.variants[variant],
+                                            donate_argnums=0)
         return self._jitted[variant]
 
     def apply(self, state, batch_all: Dict, step_idx: int):

@@ -100,6 +100,9 @@ def train(model, tc: TrainConfig, batches: Callable[[int], Dict],
     that step (stacked with a leading n axis for codist strategies — it owns
     coordinated vs. independent sampling).
 
+    A supplied ``state`` is donated to the compiled step: the caller must
+    not read it after the call (pass a copy to keep it).
+
     ``tracer``/``metrics`` are optional ``repro.obs`` hooks on the step
     clock (one step renders as 1 ms): per-step spans with exchange markers
     and comm-byte counters. ``watch`` is an optional Watchtower on the same

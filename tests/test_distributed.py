@@ -40,7 +40,7 @@ from repro.train import stack_batches, init_codist_state
 from repro.train.engine import (AllReduce, PredictionExchange,
                                 build_train_step)
 from repro.optim import make_optimizer
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch import sharding as sh
 
 cfg = replace(get_reduced('qwen1.5-0.5b'), num_layers=2, d_model=64,
@@ -74,7 +74,7 @@ batch_sh = sh.batch_shardings(jax.eval_shape(lambda: batch), mesh,
                               stacked=True)
 state_p = jax.device_put(state, state_sh)
 batch_p = jax.device_put(batch, batch_sh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     out_state, out_metrics = jax.jit(
         step, in_shardings=(state_sh, batch_sh))(state_p, batch_p)
 loss = float(out_metrics['loss'])
@@ -99,7 +99,7 @@ state_sds = jax.eval_shape(lambda: state)
 state_sh = sh.state_shardings(state_sds, mesh, stacked=True)
 batch_sds = jax.eval_shape(lambda: batch)
 batch_sh = sh.batch_shardings(batch_sds, mesh, stacked=True)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     comp_c = jax.jit(step, in_shardings=(state_sh, batch_sh)).lower(
         state_sds, batch_sds).compile()
 coll_c = parse_collectives(comp_c.as_text(), devices_per_pod=4)
@@ -112,7 +112,7 @@ ar_step = build_train_step(model, tc, None, AllReduce()).variants['on']
 ar_state_sds = jax.eval_shape(lambda: ar_state)
 ar_state_sh = sh.state_shardings(ar_state_sds, mesh)
 ar_batch_sh = sh.batch_shardings(jax.eval_shape(lambda: ar_batch), mesh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     comp_a = jax.jit(ar_step, in_shardings=(ar_state_sh, ar_batch_sh)).lower(
         ar_state_sds, jax.eval_shape(lambda: ar_batch)).compile()
 coll_a = parse_collectives(comp_a.as_text(), devices_per_pod=4)
@@ -141,8 +141,8 @@ import repro.launch.mesh as mesh_mod
 # shrink the production mesh + arch for CI
 orig = mesh_mod.make_production_mesh
 def small_mesh(*, multi_pod=False):
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod \
-        else jax.make_mesh((4, 2), ("data", "model"))
+    return mesh_mod.auto_mesh((2, 2, 2), ("pod", "data", "model")) \
+        if multi_pod else mesh_mod.auto_mesh((4, 2), ("data", "model"))
 dr.make_production_mesh = small_mesh
 orig_cfg = dr.dryrun_config
 from repro.configs import get_reduced

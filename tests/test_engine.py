@@ -94,7 +94,9 @@ class TestStrategyParity:
     def _run_codist(self, model, stacked, strategy_cls, **cfg_kw):
         codist = CodistConfig(n_models=N, alpha0=0.0, **cfg_kw)
         _, hist = train_codist(model, codist, self._tc(lr_scale=N),
-                               coord_batches(), state=stacked, log_every=1,
+                               coord_batches(),
+                               state=jax.tree.map(jnp.copy, stacked),
+                               log_every=1,
                                strategy=strategy_cls(codist))
         return hist
 
@@ -205,8 +207,9 @@ class TestTrainableMask:
                               state.params)
         bundle = build_train_step(model, tc, codist, strategy,
                                   trainable=frozen)
+        before = jax.tree.map(np.asarray, state.params)  # state is donated
         new_state, _, _ = bundle.apply(state, batch, 0)
-        for a, b in zip(jax.tree.leaves(state.params),
+        for a, b in zip(jax.tree.leaves(before),
                         jax.tree.leaves(new_state.params)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -307,7 +310,8 @@ def batches(step):
     return stack_batches([make_lm_batch(task, 4, 16, step, None, seed=0)
                           for _ in range(2)])
 _, h_pred = train_codist(model, codist, tc, batches, log_every=1)
-mesh = jax.make_mesh((2,), ('pod',))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2,), ('pod',))
 _, h_sm = train_codist(model, codist, tc, batches, log_every=1,
                        strategy=ShardMapCompressed(codist, mesh))
 print('RESULT ' + json.dumps({

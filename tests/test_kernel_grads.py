@@ -123,13 +123,14 @@ class TestCombinedKernelGrads:
 # ----------------------------------------------------------------------------
 
 def _iter_eqns(jaxpr):
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
         for val in jax.tree.leaves(eqn.params, is_leaf=lambda x: isinstance(
-                x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-            if isinstance(val, jax.core.ClosedJaxpr):
+                x, (Jaxpr, ClosedJaxpr))):
+            if isinstance(val, ClosedJaxpr):
                 yield from _iter_eqns(val.jaxpr)
-            elif isinstance(val, jax.core.Jaxpr):
+            elif isinstance(val, Jaxpr):
                 yield from _iter_eqns(val)
 
 
@@ -137,7 +138,7 @@ def _iter_eqns(jaxpr):
 # (T, V) gradient — not math temporaries (inner jaxprs are recursed anyway)
 _ALLOWED_TV_PRODUCERS = {"pallas_call", "reshape", "squeeze", "slice",
                          "transpose", "copy", "convert_element_type",
-                         "pjit", "custom_vjp_call", "custom_vjp_call_jaxpr",
+                         "jit", "custom_vjp_call", "custom_vjp_call_jaxpr",
                          "custom_jvp_call"}
 
 

@@ -1,5 +1,10 @@
 """Unit tests for the launch substrate: HLO collective parsing, sharding
 rules, roofline math, comm-cost integration — no device mesh needed."""
+import os
+import shutil
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -95,8 +100,8 @@ class TestShardingRules:
     @pytest.fixture(scope="class")
     def mesh(self):
         # AbstractMesh avoids touching real devices
-        from repro.launch.mesh import abstract_mesh
-        return abstract_mesh((16, 16), ("data", "model"))
+        from jax.sharding import AbstractMesh
+        return AbstractMesh((16, 16), ("data", "model"))
 
     def test_attention_head_fallback_replicates(self, mesh):
         from repro.launch.sharding import param_spec
@@ -130,17 +135,19 @@ class TestShardingRules:
         assert spec[0] is None
 
     def test_stacked_codist_axis(self):
-        from repro.launch.mesh import abstract_mesh
+        from jax.sharding import AbstractMesh
+
         from repro.launch.sharding import param_spec
-        mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+        mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
         spec = param_spec("layers/sub0/ffn/w_up", (2, 24, 1024, 2816), mesh,
                           stacked=True, scanned=True)
         assert spec[0] == "pod" and spec[1] is None
 
     def test_two_d_ffn_decode(self):
-        from repro.launch.mesh import abstract_mesh
+        from jax.sharding import AbstractMesh
+
         from repro.launch.sharding import param_spec
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         spec = param_spec("layers/sub0/ffn/w_up", (28, 3584, 18944), mesh,
                           scanned=True, two_d_ffn=True)
         assert spec[2] == ("data", "model")
@@ -166,3 +173,45 @@ class TestHierarchicalTopK:
         x = jax.random.normal(jax.random.key(0), (3, 100))
         v, i = _hierarchical_topk(x, 50, segments=16)  # 100/16 < 50 -> fallback
         assert v.shape == (3, 50)
+
+
+# ----------------------------------------------------------------------------
+# chip entry points: no CPU fallback, fixed compile-cache location
+# ----------------------------------------------------------------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.mark.parametrize("where", ["checkout", "alone"])
+def test_chip_smoke_refuses_to_run_without_the_chip(where, tmp_path):
+    """Under JAX_PLATFORMS=cpu (and with nothing of the repo beside it) the
+    smoke exits non-zero before any phase and prints no result line."""
+    script = os.path.join(_ROOT, "chip_smoke.py")
+    if where == "alone":
+        shutil.copy(script, tmp_path / "chip_smoke.py")
+        script = str(tmp_path / "chip_smoke.py")
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, script], cwd=os.path.dirname(script),
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "ok:" not in out.stdout            # no phase ran
+    expect = "no TPU" if where == "checkout" else "src/ is not beside"
+    assert expect in out.stderr
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == os.path.join(_ROOT, ".jax_cache")
+        assert str(CHECKOUT) == _ROOT
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

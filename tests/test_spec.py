@@ -237,8 +237,10 @@ def test_verify_step_matches_sequential_decode(fused):
                             jnp.asarray(pool.lengths), jnp.asarray(wslot),
                             jnp.asarray(woff), jnp.asarray(toks[:, j:j + 1]))
         pool.kv, pool.states = kv, st
-        pool.lengths += 1
+        # wait for the step before bumping the host lengths: on the CPU
+        # jnp.asarray may alias that numpy buffer while the step still runs
         seq_logits.append(np.asarray(lg))
+        pool.lengths += 1
     seq_leaves = [np.asarray(x) for x in jax.tree.leaves(pool.kv)]
 
     # one batched verify over the same k tokens

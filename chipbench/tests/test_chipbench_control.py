@@ -1,0 +1,20 @@
+"""The control at a size a test run can hold: the reference in float8 fails
+the cell's limits, and reads above the program in its own bfloat16. (At this
+size bfloat16 alone reads near the limits, which are set for the cell's
+published widths: see PERF.md.)"""
+from chipbench import control, manifest
+from chipbench.tests import tiny
+
+
+def test_control_fails_where_the_program_passes(tmp_path, monkeypatch):
+    bench, cell = tiny.bench_and_cell(tmp_path)
+    monkeypatch.setattr(manifest, "traffic",
+                        lambda name, _real=manifest.traffic: dict(
+                            _real(name), batch=2, seq=32))
+    readers = control.Readers(bench, cell)
+    row = readers(2**31 + 11, extra=True)
+    assert row["control"]["correct"] is False, row["control"]
+    for k in ("grad_gap", "change_gap"):
+        assert row["program"][k][0] < row["control"][k][0] / 2, (
+            row["program"], row["control"])
+    assert row["half_batch"]["correct"] is False, row["half_batch"]

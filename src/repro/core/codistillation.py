@@ -317,6 +317,7 @@ def distill_vs_compressed(cfg: CodistConfig, logits: jax.Array, wire: Dict,
 # Algorithm 1: the combined codistillation loss over stacked logits
 # ----------------------------------------------------------------------------
 
+@jax.named_scope("loss")
 def codist_loss(cfg: CodistConfig,
                 logits_all: jax.Array,          # (n, ..., V)
                 labels_all: jax.Array,          # (n, ...)
@@ -372,7 +373,10 @@ def codist_loss(cfg: CodistConfig,
     # mesh axis (it would move the raw logits cross-pod and compress after),
     # so when a pod-axis mesh is active the compression runs inside a narrow
     # shard_map manual over "pod" — correctness identical, schedule pinned.
-    wires_all = _compress_stacked(cfg, targets)
+    # the targets each model sees ("exchange"): the only values that would
+    # cross pods, so the only place a collective can appear
+    with jax.named_scope("exchange"):
+        wires_all = _compress_stacked(cfg, targets)
     use_fused = _fused_enabled(fused)
 
     task_losses = []
@@ -380,13 +384,14 @@ def codist_loss(cfg: CodistConfig,
     for i in range(n):
         m_i = None if mask_all is None else mask_all[i]
         wires_i = []
-        for j in range(n):
-            if j == i:
-                continue
-            if peer_pairwise is not None:
-                wires_i.append(compress_targets(cfg, peer_pairwise[i, j]))
-            else:
-                wires_i.append(jax.tree.map(lambda x: x[j], wires_all))
+        with jax.named_scope("exchange"):
+            for j in range(n):
+                if j == i:
+                    continue
+                if peer_pairwise is not None:
+                    wires_i.append(compress_targets(cfg, peer_pairwise[i, j]))
+                else:
+                    wires_i.append(jax.tree.map(lambda x: x[j], wires_all))
         # hot path: fuse the task CE with the first distillation term so the
         # student logits are swept once (combined kernel); extra peers reuse
         # the streaming pairwise kernel.

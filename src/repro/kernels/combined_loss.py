@@ -149,6 +149,7 @@ def fused_ce_distill_parts(logits: jax.Array, target_logits: jax.Array,
         out_specs=[_tok_spec(block_t) for _ in range(n_out)],
         out_shape=tok_out(t, n_out),
         scratch_shapes=[tok_scratch(block_t) for _ in range(n_scratch)],
+        name="fused_ce_distill_parts",
         interpret=interpret,
     )(col(labels), logits, target_logits)
     outs = tuple(o[:, 0] for o in outs)
@@ -220,6 +221,7 @@ def fused_ce_distill_grad(logits: jax.Array, target_logits: jax.Array,
                    _tile_spec(block_t, block_v)],
         out_shape=[jax.ShapeDtypeStruct((t, v), logits.dtype),
                    jax.ShapeDtypeStruct((t, v), target_logits.dtype)],
+        name="fused_ce_distill_grad",
         interpret=interpret,
     )(*map(col, (labels, *residuals, g_nll, g_smooth, g_dist)), logits,
       target_logits)

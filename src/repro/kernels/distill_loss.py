@@ -163,6 +163,7 @@ def fused_distill_loss(logits: jax.Array, target_logits: jax.Array,
         out_specs=_tok_spec(block_t),
         out_shape=tok_out(t, 1)[0],
         scratch_shapes=scratch,
+        name=f"fused_distill_{mode}",
         interpret=interpret,
     )(logits, target_logits)[:, 0]
 
@@ -185,6 +186,7 @@ def fused_distill_kl_parts(logits: jax.Array, target_logits: jax.Array,
         out_specs=[_tok_spec(block_t) for _ in range(4)],
         out_shape=tok_out(t, 4),
         scratch_shapes=[tok_scratch(block_t) for _ in range(5)],
+        name="fused_distill_kl_parts",
         interpret=interpret,
     )(logits, target_logits)
     return tuple(o[:, 0] for o in outs)
@@ -232,6 +234,7 @@ def fused_distill_mse_grad(logits: jax.Array, target_logits: jax.Array,
                    _tile_spec(block_t, block_v)],
         out_shape=[jax.ShapeDtypeStruct((t, v), logits.dtype),
                    jax.ShapeDtypeStruct((t, v), target_logits.dtype)],
+        name="fused_distill_mse_grad",
         interpret=interpret,
     )(logits, target_logits, col(g))
 
@@ -259,5 +262,6 @@ def fused_distill_kl_grad(logits: jax.Array, target_logits: jax.Array,
                    _tile_spec(block_t, block_v)],
         out_shape=[jax.ShapeDtypeStruct((t, v), logits.dtype),
                    jax.ShapeDtypeStruct((t, v), target_logits.dtype)],
+        name="fused_distill_kl_grad",
         interpret=interpret,
     )(logits, target_logits, col(logzs), col(logzt), col(e), col(g))

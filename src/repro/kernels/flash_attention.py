@@ -106,6 +106,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)

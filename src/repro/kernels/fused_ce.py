@@ -150,6 +150,7 @@ def fused_cross_entropy_parts(logits: jax.Array, labels: jax.Array,
         out_specs=[tok_spec(block_t) for _ in range(3)],
         out_shape=tok_out(t, 3),
         scratch_shapes=[tok_scratch(block_t) for _ in range(4)],
+        name="fused_cross_entropy_parts",
         interpret=interpret,
     )(col(labels), logits)
     return tuple(o[:, 0] for o in outs)
@@ -204,5 +205,6 @@ def fused_cross_entropy_grad(logits: jax.Array, labels: jax.Array,
         in_specs=[tok_spec(block_t)] * 4 + [tile_spec(block_t, block_v)],
         out_specs=tile_spec(block_t, block_v),
         out_shape=jax.ShapeDtypeStruct((t, v), logits.dtype),
+        name="fused_cross_entropy_grad",
         interpret=interpret,
     )(col(labels), col(logz), col(g_nll), col(g_smooth), logits)

@@ -166,6 +166,7 @@ def paged_attention_decode(q: jax.Array, k_pool: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s, kvh, g, hd), q.dtype),
+        name="paged_attention",
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), n_live,
       *operands)

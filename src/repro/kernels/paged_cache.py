@@ -78,6 +78,7 @@ def paged_gather(pool: jax.Array, table: jax.Array, n_live: jax.Array,
                                    lambda si, mi, t, nl: (si, mi, 0, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((s, mb, bs, kv, hd), pool.dtype),
+        name="paged_cache_gather",
         interpret=interpret,
     )(table.astype(jnp.int32), n_live.astype(jnp.int32), pool)
     return out.reshape(s, mb * bs, kv, hd)
@@ -139,6 +140,7 @@ def paged_scatter(pool: jax.Array, new: jax.Array, write_slot: jax.Array,
                                    lambda b, ws, wo: (b, 0, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        name="paged_cache_scatter",
         interpret=interpret,
     )(write_slot.astype(jnp.int32), write_off.astype(jnp.int32),
       new.astype(pool.dtype), pool)
@@ -247,6 +249,7 @@ def paged_scatter_quant(pool: jax.Array, scales: jax.Array, new: jax.Array,
         ),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((nb, 1, bs), jnp.float32)],
+        name="paged_cache_scatter_quant",
         interpret=interpret,
     )(write_slot.astype(jnp.int32), write_off.astype(jnp.int32),
       new.astype(jnp.float32), pool,

@@ -344,6 +344,10 @@ def main() -> None:
         save_pytree(os.path.join(args.out, "final"), state.params)
         print(f"wrote {args.out}/history.json and final checkpoint")
     _save_obs()
+    # a variant traced again mid-run was recompiled: its step names the cause
+    print("step variants traced at steps: " + " ".join(
+        f"{v}={','.join(map(str, ks))}"
+        for v, ks in sorted(hist.traced_at.items())), flush=True)
 
 
 if __name__ == "__main__":

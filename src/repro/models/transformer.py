@@ -92,22 +92,26 @@ def _init_scan_step(key: jax.Array, cfg: ModelConfig, dtype) -> Dict:
 
 def _sublayer_fwd(p: Dict, x: jax.Array, cfg: ModelConfig, mixer: str,
                   ffn: str, positions: Optional[jax.Array]):
+    # the named scopes (the mixer's kind, "mlp") tag the device ops of each
+    # half of the layer, forward, recompute and backward, for the profiler
     aux = jnp.zeros((), jnp.float32)
-    h = apply_norm(p["norm1"], x, cfg.norm_eps)
-    if mixer == "attn":
-        h, _ = attn.attention_forward(p["mix"], h, cfg, positions)
-    elif mixer == "ssm":
-        h = mb.mamba_forward(p["mix"], h, cfg)
-    else:  # rwkv time mix
-        h, _ = rk.time_mix_forward(p["mix"], h, cfg)
+    with jax.named_scope(mixer):
+        h = apply_norm(p["norm1"], x, cfg.norm_eps)
+        if mixer == "attn":
+            h, _ = attn.attention_forward(p["mix"], h, cfg, positions)
+        elif mixer == "ssm":
+            h = mb.mamba_forward(p["mix"], h, cfg)
+        else:  # rwkv time mix
+            h, _ = rk.time_mix_forward(p["mix"], h, cfg)
     x = x + h
-    h = apply_norm(p["norm2"], x, cfg.norm_eps)
-    if ffn == "moe":
-        h, aux = moe_forward(p["ffn"], h, cfg)
-    elif ffn == "rwkv":
-        h, _ = rk.channel_mix_forward(p["ffn"], h, cfg)
-    else:
-        h = ffn_forward(p["ffn"], h, cfg)
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["norm2"], x, cfg.norm_eps)
+        if ffn == "moe":
+            h, aux = moe_forward(p["ffn"], h, cfg)
+        elif ffn == "rwkv":
+            h, _ = rk.channel_mix_forward(p["ffn"], h, cfg)
+        else:
+            h = ffn_forward(p["ffn"], h, cfg)
     return x + h, aux
 
 
@@ -128,8 +132,9 @@ def _scan_forward(layers: PyTree, x: jax.Array, cfg: ModelConfig,
     from repro.models.runtime_flags import scan_unroll
     if remat:
         body = jax.checkpoint(body)
-    x, auxs = jax.lax.scan(body, x, layers, unroll=scan_unroll())
-    return x, jnp.sum(auxs)
+    with jax.named_scope("layers"):
+        x, auxs = jax.lax.scan(body, x, layers, unroll=scan_unroll())
+        return x, jnp.sum(auxs)
 
 
 # ----------------------------------------------------------------------------
@@ -233,15 +238,17 @@ class LM:
     def _embed(self, params: PyTree, batch: Dict) -> Tuple[jax.Array, jax.Array]:
         cfg = self.cfg
         dtype = cfg.activation_dtype
-        x = embed_tokens(params["embed"], batch["tokens"], dtype)
-        if cfg.num_patches and "patches" in batch:
-            # VLM: precomputed patch embeddings prefix (stub frontend)
-            x = jnp.concatenate([batch["patches"].astype(dtype), x], axis=1)
-        if "embed_norm" in params:
-            x = apply_norm(params["embed_norm"], x, cfg.norm_eps)
-        from repro.models.sharding_hints import hint
-        x = hint(x, "btd")
-        positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+        with jax.named_scope("embed"):
+            x = embed_tokens(params["embed"], batch["tokens"], dtype)
+            if cfg.num_patches and "patches" in batch:
+                # VLM: precomputed patch embeddings prefix (stub frontend)
+                x = jnp.concatenate([batch["patches"].astype(dtype), x],
+                                    axis=1)
+            if "embed_norm" in params:
+                x = apply_norm(params["embed_norm"], x, cfg.norm_eps)
+            from repro.models.sharding_hints import hint
+            x = hint(x, "btd")
+            positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
         return x, positions
 
     # -- train forward --------------------------------------------------------
@@ -250,10 +257,11 @@ class LM:
         cfg = self.cfg
         x, positions = self._embed(params, batch)
         x, aux = _scan_forward(params["layers"], x, cfg, positions, remat)
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
-        if cfg.num_patches and "patches" in batch:
-            x = x[:, batch["patches"].shape[1]:]  # logits only over text tokens
-        return lm_head(params["embed"], x), aux
+        with jax.named_scope("head"):
+            x = apply_norm(params["final_norm"], x, cfg.norm_eps)
+            if cfg.num_patches and "patches" in batch:
+                x = x[:, batch["patches"].shape[1]:]  # logits only over text
+            return lm_head(params["embed"], x), aux
 
     # -- serving --------------------------------------------------------------
     def init_cache(self, batch: int, cap: int, dtype=jnp.bfloat16) -> PyTree:

@@ -27,6 +27,14 @@ Surfaced as ``--trace out.json --metrics out-metrics.json`` on
 ``repro.launch.train``, ``repro.launch.serve`` and ``repro.launch.sweep``;
 ``tools/trace_check.py`` validates exported traces in CI. See
 docs/observability.md.
+
+Two clocks. This package's tracer runs on simulated clocks: it makes
+deterministic CI artifacts and never measures speed. Time on the chip is
+read from the JAX profiler's own clock: the named scopes on the training
+step's layers (``embed``, ``layers``/``attn``/``mlp``, ``head``, ``loss``,
+``exchange``, ``optimizer``, ``metrics``) and the ``train.dispatch`` /
+``train.host_exchange`` spans of ``StepBundle.apply``, which
+``chipbench`` reduces from a traced run on the chip.
 """
 from repro.obs.fsio import atomic_write_text  # noqa: F401
 from repro.obs.metrics import (DEFAULT_BUCKETS, GAUGE_WINDOW,  # noqa: F401

@@ -47,7 +47,7 @@ every caller migrated here; this module is the only way to build steps.
 from __future__ import annotations
 
 from dataclasses import replace
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -170,6 +170,7 @@ def _param_bits(params: PyTree, n: int = 1) -> float:
     return total / max(1, n)
 
 
+@jax.named_scope("loss")
 def _plain_task_metrics(codist, logits_all, batch, ls, fused):
     """Stacked task-only loss (the prediction off-step / alpha=0 shape)."""
     task = jax.vmap(
@@ -255,19 +256,22 @@ class ExchangeStrategy:
         """Return ``(total, metrics, aux)`` for one (micro)batch."""
         logits_all, aux_all = _stacked_forward(model, params, batch, tc.remat)
         if variant == "on":
+            with jax.named_scope("exchange"):
+                targets = self.distill_targets(model, tc, state, batch,
+                                               logits_all)
             total, metrics = cd.codist_loss(
                 self.codist, logits_all, batch["labels"],
                 sch.alpha(state.step), sch.ls(state.step), batch.get("mask"),
-                fused=tc.fused_losses,
-                **self.distill_targets(model, tc, state, batch, logits_all))
+                fused=tc.fused_losses, **targets)
         else:
             total, metrics = _plain_task_metrics(
                 self.codist, logits_all, batch, sch.ls(state.step),
                 tc.fused_losses)
         total = total + jnp.mean(aux_all)
-        metrics["aux_loss"] = jnp.mean(aux_all)
-        metrics["accuracy"] = jnp.mean(jax.vmap(cd.accuracy)(
-            logits_all, batch["labels"]))
+        with jax.named_scope("metrics"):
+            metrics["aux_loss"] = jnp.mean(aux_all)
+            metrics["accuracy"] = jnp.mean(jax.vmap(cd.accuracy)(
+                logits_all, batch["labels"]))
         return total, metrics, None
 
     def post_update(self, state, params, opt, batch_all: Dict, aux, k: int):
@@ -300,11 +304,14 @@ class AllReduce(ExchangeStrategy):
 
     def loss(self, model, tc, sch, state, params, batch, variant):
         logits, aux = _task_forward(model, params, batch, tc.remat)
-        task = cd.cross_entropy(logits, batch["labels"], sch.ls(state.step),
-                                batch.get("mask"), fused=tc.fused_losses)
-        metrics = {"loss": task + aux, "task_loss": task, "aux_loss": aux,
-                   "accuracy": cd.accuracy(logits, batch["labels"],
-                                           batch.get("mask"))}
+        with jax.named_scope("loss"):
+            task = cd.cross_entropy(logits, batch["labels"],
+                                    sch.ls(state.step), batch.get("mask"),
+                                    fused=tc.fused_losses)
+        with jax.named_scope("metrics"):
+            metrics = {"loss": task + aux, "task_loss": task, "aux_loss": aux,
+                       "accuracy": cd.accuracy(logits, batch["labels"],
+                                               batch.get("mask"))}
         return task + aux, metrics, None
 
     def prepare(self, state, batch_all, k):
@@ -646,21 +653,26 @@ class ShardMapCompressed(PredictionExchange):
             p = jax.tree.map(lambda x: x[0], params_1)
             b = jax.tree.map(lambda x: x[0], batch_1)
             logits, aux = _task_forward(model, p, b, tc.remat)
-            task = cd.cross_entropy(logits, b["labels"], sch.ls(state.step),
-                                    b.get("mask"), fused=tc.fused_losses)
+            with jax.named_scope("loss"):
+                task = cd.cross_entropy(logits, b["labels"],
+                                        sch.ls(state.step), b.get("mask"),
+                                        fused=tc.fused_losses)
             # local compression, explicit cross-pod gather of the wire
-            wire = cd.compress_targets(codist, jax.lax.stop_gradient(logits))
-            wires_all = jax.tree.map(
-                lambda x: jax.lax.all_gather(x, "pod"), wire)
+            with jax.named_scope("exchange"):
+                wire = cd.compress_targets(codist,
+                                           jax.lax.stop_gradient(logits))
+                wires_all = jax.tree.map(
+                    lambda x: jax.lax.all_gather(x, "pod"), wire)
             idx = jax.lax.axis_index("pod")
             dist = jnp.zeros((), jnp.float32)
-            for j in range(n):
-                wire_j = jax.tree.map(lambda x: x[j], wires_all)
-                d = cd.distill_vs_compressed(codist, logits, wire_j,
-                                             b.get("mask"),
-                                             fused=tc.fused_losses)
-                dist = dist + jnp.where(idx == j, 0.0, d)
-            dist = dist / (n - 1)
+            with jax.named_scope("loss"):
+                for j in range(n):
+                    wire_j = jax.tree.map(lambda x: x[j], wires_all)
+                    d = cd.distill_vs_compressed(codist, logits, wire_j,
+                                                 b.get("mask"),
+                                                 fused=tc.fused_losses)
+                    dist = dist + jnp.where(idx == j, 0.0, d)
+                dist = dist / (n - 1)
             total = task + sch.alpha(state.step) * dist + aux
             out = jnp.stack([total, task, dist, aux])
             return out[None]  # (1, 4): pod-sharded metrics row
@@ -709,7 +721,11 @@ STRATEGIES = {cls.name: cls for cls in
 # ----------------------------------------------------------------------------
 
 class StepBundle:
-    """Compiled variants of one strategy plus the plan-driven dispatcher."""
+    """Compiled variants of one strategy plus the plan-driven dispatcher.
+
+    ``traced_at[variant]`` lists the step index of every trace of that
+    variant (``None`` for a trace outside ``apply``, such as ``lower``): a
+    second entry is a recompile, at the step whose batch caused it."""
 
     def __init__(self, strategy: ExchangeStrategy,
                  variants: Dict[str, Callable], eval_fn: Callable):
@@ -717,23 +733,38 @@ class StepBundle:
         self.variants = variants     # raw (unjitted) step fns
         self.eval_fn = eval_fn       # raw eval fn
         self._jitted: Dict[str, Callable] = {}
+        self.traced_at: Dict[str, List[Optional[int]]] = {}
+        self._step: Optional[int] = None
 
     def jitted(self, variant: str = "on") -> Callable:
         """The compiled variant. It donates the state (argument 0): the
         caller must not read the state it passed in after the call."""
         if variant not in self._jitted:
-            self._jitted[variant] = jax.jit(self.variants[variant],
-                                            donate_argnums=0)
+            fn = self.variants[variant]
+
+            def step(state, batch_all):
+                # runs only while JAX traces the variant
+                self.traced_at.setdefault(variant, []).append(self._step)
+                return fn(state, batch_all)
+            self._jitted[variant] = jax.jit(step, donate_argnums=0)
         return self._jitted[variant]
 
     def apply(self, state, batch_all: Dict, step_idx: int):
         """One host-loop iteration: plan -> (optional) host exchange ->
-        compiled variant. Returns ``(state, metrics, plan)``."""
+        compiled variant. Returns ``(state, metrics, plan)``.
+
+        The host spans ``train.host_exchange`` and ``train.dispatch`` carry
+        the step index and land in a profiler trace when one is running."""
         plan = self.strategy.plan(step_idx)
         if plan.exchange:
-            state = self.strategy.host_exchange(state)
-        state, metrics = self.jitted(self.strategy.variant_for(plan))(
-            state, batch_all)
+            with jax.profiler.TraceAnnotation("train.host_exchange",
+                                              step=step_idx):
+                state = self.strategy.host_exchange(state)
+        step = self.jitted(self.strategy.variant_for(plan))
+        self._step = step_idx
+        with jax.profiler.TraceAnnotation("train.dispatch", step=step_idx):
+            state, metrics = step(state, batch_all)
+        self._step = None
         return state, metrics, plan
 
 
@@ -760,10 +791,12 @@ def build_train_step(model, tc: TrainConfig, codist: Optional[CodistConfig],
             grads, metrics, aux = _grads_metrics_aux(
                 loss_fn, state.params, operand, tc.microbatch,
                 jnp.dtype(tc.accum_dtype))
-            params, opt = opt_update(state.params, grads, state.opt,
-                                     sch.lr(state.step), sch.wd(state.step),
-                                     trainable)
-            metrics.update(lr=sch.lr(state.step), wd=sch.wd(state.step))
+            with jax.named_scope("optimizer"):
+                params, opt = opt_update(state.params, grads, state.opt,
+                                         sch.lr(state.step),
+                                         sch.wd(state.step), trainable)
+            with jax.named_scope("metrics"):
+                metrics.update(lr=sch.lr(state.step), wd=sch.wd(state.step))
             new_state = strategy.post_update(state, params, opt, batch_all,
                                              aux, tc.microbatch)
             return new_state, metrics

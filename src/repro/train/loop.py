@@ -32,6 +32,8 @@ HISTORY_SCHEMA_VERSION = 1
 @dataclass
 class History:
     records: List[Dict[str, float]] = field(default_factory=list)
+    # per step variant, the step index of each trace (StepBundle.traced_at)
+    traced_at: Dict[str, List[Optional[int]]] = field(default_factory=dict)
 
     def log(self, step: int, metrics: Dict[str, Any], **extra):
         rec = {"step": step}
@@ -166,6 +168,7 @@ def train(model, tc: TrainConfig, batches: Callable[[int], Dict],
                         sum(losses) / len(losses))
             if watch is not None:
                 watch.evaluate(k)
+    hist.traced_at = bundle.traced_at
     if mreg is not None:
         mreg.counter("train/comm_events").inc(comm_events)
         mreg.counter("train/comm_bytes").inc(comm_events * bytes_per_event)

@@ -4,6 +4,7 @@ comm accounting — plus subprocess checks for the shard_map strategy (which
 needs a multi-device "pod" axis)."""
 import json
 import os
+import re
 import subprocess
 import sys
 from dataclasses import replace
@@ -214,6 +215,23 @@ class TestTrainableMask:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_traced_at_records_a_recompile_at_the_step_that_caused_it():
+    """Each variant records the step of each of its traces: a batch of a new
+    shape at step 3 traces the off variant a second time, there."""
+    model = build_model(tiny_cfg())
+    codist = CodistConfig(n_models=N, alpha0=1.0, period=2)
+    tc = TrainConfig(lr=1e-2, total_steps=4, warmup_steps=0,
+                     optimizer="sgdm", seed=0)
+    strategy = resolve_strategy(codist)
+    opt_init, _ = make_optimizer("sgdm")
+    state = strategy.init_state(model, tc, jax.random.key(0), opt_init)
+    bundle = build_train_step(model, tc, codist, strategy)
+    for k in range(4):
+        batch = coord_batches(s=S if k < 3 else S // 2)(k)
+        state, _, _ = bundle.apply(state, batch, k)
+    assert bundle.traced_at == {"on": [0], "off": [1, 3]}
+
+
 # ----------------------------------------------------------------------------
 # plan schedules + comm accounting
 # ----------------------------------------------------------------------------
@@ -338,3 +356,6 @@ def test_cli_codist_shardmap_smoke():
         capture_output=True, text=True, env=env, timeout=520)
     assert out.returncode == 0, f"stderr:\n{out.stderr[-4000:]}"
     assert "done: 3 steps" in out.stdout
+    # the last line names the step of each trace of each step variant
+    assert re.fullmatch(r"step variants traced at steps: on=0(,\d)*",
+                        out.stdout.splitlines()[-1])

@@ -18,6 +18,10 @@ Differentiable entry points (drop-ins for the jnp losses in
     kernel that reads each (T, V) logits tile exactly once per model and
     emits both losses (and both gradients on the way back).
 
+``fused_causal_attention`` is the differentiable causal attention core
+(``kernels/causal_attention.py``) that ``models.attention`` routes training
+and prefill through on the TPU.
+
 The custom-VJP boundary sits at the per-token level: masking, label-smoothing
 mixing and the mean-reduction stay in plain (T,)-sized differentiable jnp, so
 no (T, V) fp32 temporary exists outside the kernels in either direction.
@@ -31,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import causal_attention
 from repro.kernels.combined_loss import (
     fused_ce_distill_grad,
     fused_ce_distill_parts,
@@ -137,6 +142,32 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
     out = flash_attention(qp, kp, vp, causal=causal, window=window,
                           block_q=bq, block_k=bk, interpret=interpret)
     return out[:, :sq]
+
+
+def fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """Causal GQA attention core through the fused kernels
+    (``kernels/causal_attention.py``), differentiable.
+
+    q: (B, S, H, hd); k, v: (B, S, KV, hd) with H % KV == 0 and
+    ``causal_attention.supports(S, hd)``. Returns softmax(q k^T / sqrt(hd))
+    v, causal, as (B, S, H, hd) in q's dtype; the kernels scale q in fp32
+    before its cast back. The kernels work sequence-minor, the layout the
+    projections already have on the TPU, so the transposes here move no
+    data there. S is padded to the kernels' multiple: the causal mask makes
+    padded keys unreachable from real queries, and padded query rows are
+    sliced off (their zero cotangents give the real rows nothing).
+    """
+    interpret = auto_interpret() if interpret is None else interpret
+    b, s, h, hd = q.shape
+    sp = _round_up(s, causal_attention.SEQ_MULTIPLE)
+
+    def seq_minor(x):                  # (B, S, N, hd) -> (B, N*hd, Sp)
+        return jnp.swapaxes(_pad_to(x, 1, sp).reshape(b, sp, -1), 1, 2)
+
+    o = causal_attention.causal_attention(seq_minor(q), seq_minor(k),
+                                          seq_minor(v), hd, bool(interpret))
+    return jnp.swapaxes(o, 1, 2).reshape(b, sp, h, hd)[:, :s]
 
 
 # ----------------------------------------------------------------------------

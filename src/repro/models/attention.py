@@ -16,7 +16,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import KeyGen, apply_rope, dense_init, zeros
+from repro.kernels import causal_attention, ops
+from repro.models import sharding_hints as hints
+from repro.models.common import (KeyGen, apply_rope, apply_rope_halves,
+                                  dense_init, zeros)
 
 NEG_INF = -1e30
 
@@ -87,6 +90,36 @@ def _softmax(scores: jax.Array) -> jax.Array:
 # training / prefill forward
 # ----------------------------------------------------------------------------
 
+def _takes_fused_core(cfg: ModelConfig, causal: bool, seq: int) -> bool:
+    """Whether the S x S core (scores, mask, softmax, PV) runs as the fused
+    causal kernels (``ops.fused_causal_attention``): on the TPU, for a
+    causal unwindowed mask over whole query groups at head widths and
+    lengths the kernels take, in a program that GSPMD does not partition.
+    Everything else keeps the dense core below."""
+    return (jax.default_backend() == "tpu" and causal
+            and cfg.sliding_window == 0
+            and cfg.num_heads % cfg.num_kv_heads == 0
+            and causal_attention.supports(seq, cfg.resolved_head_dim)
+            and hints.unpartitioned())
+
+
+def _dense_core(q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig,
+                causal: bool) -> jax.Array:
+    """The S x S core in plain XLA: (B,H,S,S) bf16 scores, masked, softmaxed
+    in fp32 and cast back for the PV product. -> (B,S,H,hd)."""
+    s = q.shape[1]
+    scores = hints.hint(_gqa_scores(q, k), "scores")  # (B,H,S,S)
+    if causal:
+        i = jnp.arange(s)[:, None]
+        j = jnp.arange(s)[None, :]
+        mask = j <= i
+        if cfg.sliding_window > 0:
+            mask = mask & (i - j < cfg.sliding_window)
+        scores = jnp.where(mask[None, None], scores, NEG_INF)
+    w = _softmax(scores).astype(q.dtype)
+    return _gqa_combine(w, v)
+
+
 def attention_forward(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig,
                       positions: Optional[jax.Array] = None,
                       causal: bool = True,
@@ -99,22 +132,19 @@ def attention_forward(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig,
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
     q, k, v = _project_qkv(p, x, cfg)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-
-    from repro.models.sharding_hints import hint
-    scores = hint(_gqa_scores(q, k), "scores")  # (B,H,S,S)
-    if causal:
-        i = jnp.arange(s)[:, None]
-        j = jnp.arange(s)[None, :]
-        mask = j <= i
-        if cfg.sliding_window > 0:
-            mask = mask & (i - j < cfg.sliding_window)
-        scores = jnp.where(mask[None, None], scores, NEG_INF)
-    w = _softmax(scores).astype(x.dtype)
-    out = _out_proj(p, _gqa_combine(w, v))
+    if _takes_fused_core(cfg, causal, s):
+        # q and k in RoPE's halves order: the scores are the same sums
+        o = ops.fused_causal_attention(
+            apply_rope_halves(q, positions, cfg.rope_theta),
+            apply_rope_halves(k, positions, cfg.rope_theta), v)
+        if return_cache:
+            k = apply_rope(k, positions, cfg.rope_theta)
+    else:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        o = _dense_core(q, k, v, cfg, causal)
     cache = {"k": k, "v": v} if return_cache else None
-    return out, cache
+    return _out_proj(p, o), cache
 
 
 def cross_attention_forward(p: Dict[str, jax.Array], x: jax.Array,

@@ -99,8 +99,9 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float):
+    """Each dim pair (2i, 2i+1) of x rotated by its angle, in fp32: the
+    pairs' first elements and their second ones, (..., S, H, hd/2) each."""
     hd = x.shape[-1]
     inv = rope_frequencies(hd, theta)                     # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., S, hd/2)
@@ -109,10 +110,24 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> ja
     cos = cos[..., None, :]
     x1 = x[..., 0::2].astype(jnp.float32)
     x2 = x[..., 1::2].astype(jnp.float32)
-    y1 = x1 * cos - x2 * sin
-    y2 = x2 * cos + x1 * sin
+    return x1 * cos - x2 * sin, x2 * cos + x1 * sin
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+    y1, y2 = _rope_pairs(x, positions, theta)
     out = jnp.stack([y1, y2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
+
+
+def apply_rope_halves(x: jax.Array, positions: jax.Array,
+                      theta: float = 10000.0) -> jax.Array:
+    """``apply_rope`` with each head's dims reordered: the rotated pairs'
+    first elements, then their second ones. The dot product of two vectors
+    so ordered is that of ``apply_rope``'s, summed in another order; the
+    order needs no interleave, which on the TPU is a relayout."""
+    y1, y2 = _rope_pairs(x, positions, theta)
+    return jnp.concatenate([y1, y2], axis=-1).astype(x.dtype)
 
 
 # ----------------------------------------------------------------------------

@@ -54,6 +54,19 @@ def _active() -> bool:
         getattr(_state, "tp_axis", None) is not None
 
 
+def unpartitioned() -> bool:
+    """True while tracing a program that GSPMD will not partition: no
+    activation sharding is active, and the process has one device or the
+    trace is inside a ``shard_map`` manual over every axis of its mesh. Only
+    there may a Mosaic kernel (which XLA cannot partition) be placed."""
+    if _active():
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        return mesh.are_all_axes_manual
+    return jax.device_count() == 1
+
+
 def tensor_parallel_active() -> bool:
     """True while tracing under an activation-sharding context with a tensor
     -parallel axis (the lm-head/vocab dimension may be sharded)."""

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import causal_attention
 from repro.models import attention as attn
 from repro.models import mamba as mb
 from repro.models import rwkv as rk
@@ -31,6 +32,11 @@ from repro.models.ffn import ffn_forward, init_ffn
 from repro.models.moe import init_moe, moe_forward
 
 PyTree = Any
+
+# remat keeps only the values the fused attention kernels name as their
+# residuals (none on the dense route): everything else is recomputed
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    causal_attention.RESIDUAL)
 
 
 # ----------------------------------------------------------------------------
@@ -131,7 +137,9 @@ def _scan_forward(layers: PyTree, x: jax.Array, cfg: ModelConfig,
 
     from repro.models.runtime_flags import scan_unroll
     if remat:
-        body = jax.checkpoint(body)
+        # the fused attention kernels' output and logsumexp stay, so remat
+        # reruns the projections but not the attention core
+        body = jax.checkpoint(body, policy=REMAT_POLICY)
     with jax.named_scope("layers"):
         x, auxs = jax.lax.scan(body, x, layers, unroll=scan_unroll())
         return x, jnp.sum(auxs)

@@ -10,6 +10,8 @@ TPU compiler, and a machine that cannot describe it skips here.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -98,6 +100,29 @@ def test_forward_only_cross_entropy_compiles(one_chip):
 
 
 # ----------------------------------------------------------------------------
+# the fused causal attention core, forward and backward
+# ----------------------------------------------------------------------------
+
+@pytest.mark.parametrize("heads,hd", [(H, HD), (20, 128)],
+                         ids=["qwen1.5-0.5b", "qwen1.5-4b"])
+def test_fused_causal_attention_value_and_grad_compiles(one_chip, heads, hd):
+    """Two peers' 4 x 512 rows under remat, as the scanned layers run the
+    core: the forward, its recompute and the backward kernel."""
+    from repro.kernels import ops
+    qkv = _sds(one_chip, (2, 4, 512, heads, hd), jnp.bfloat16)
+
+    def f(q, k, v):
+        core = jax.checkpoint(lambda q, k, v: ops.fused_causal_attention(
+            q, k, v, interpret=False))
+        return jnp.sum(jax.vmap(core)(q, k, v).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(f, argnums=(0, 1, 2)), qkv, qkv,
+                    qkv).as_text()
+    for kernel in ("fwd", "bwd"):
+        assert f"%causal_attention_{kernel}" in text, kernel
+
+
+# ----------------------------------------------------------------------------
 # paged decode: attention over the block pool, and the appending scatters
 # ----------------------------------------------------------------------------
 
@@ -147,15 +172,18 @@ def test_paged_scatter_compiles(one_chip, pool_dtype):
 
 def test_full_width_codist_step_fits_one_chip(one_chip, monkeypatch):
     """24 layers, 464M parameters per peer, 2 peers, SGD with momentum,
-    remat, fused losses, state donated: the step must fit a v5e's HBM."""
+    remat, fused losses and attention, state donated: the step must fit a
+    v5e's HBM."""
     from repro.kernels import ops
     from repro.models import build_model
     from repro.optim import make_optimizer
     from repro.train.engine import PredictionExchange, build_train_step
 
     # the step asks the backend (the CPU here) whether to interpret the
-    # kernels; compile them as the chip would
+    # kernels and whether attention takes the fused core; compile them as
+    # the chip would
     monkeypatch.setattr(ops, "auto_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = build_model(CFG)
     tc = TrainConfig(optimizer="sgdm", remat=True, fused_losses=True,
                      total_steps=4, warmup_steps=0)
@@ -173,6 +201,11 @@ def test_full_width_codist_step_fits_one_chip(one_chip, monkeypatch):
     compiled = bundle.jitted("on").lower(on_chip(state),
                                          on_chip(batch)).compile()
     assert _has_kernel(compiled)
+    # one forward kernel per layer: remat keeps its named residuals and
+    # does not rerun it; the backward kernel takes them
+    text = compiled.as_text()
+    assert len(re.findall(r"%causal_attention_fwd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%causal_attention_bwd[.\d]* = ", text)) == 1
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

@@ -1,6 +1,10 @@
 """One run of one cell: set-up, the measured window, the traced steps, and the
 comparison that decides ``correct``.
 
+The step is the one ``program.build`` makes from the traffic file's
+``strategy``: ``prediction`` (Algorithm 1, ``models`` peers stacked on one
+chip) or ``all_reduce`` (the paper's baseline, one model).
+
 Set-up (``setup_s``) runs from process start to the end of the third step:
 the state is made on the device from the seed in one jitted call, and the
 first three steps go through ``StepBundle.apply`` with the window's own

@@ -6,6 +6,12 @@ This is the only module of the benchmark that imports the program
 from; the weights in that state come from ``weights.make_params``, in the
 layout the configuration's plain reference declares, and the layout is
 checked against the program's own ``model.init``.
+
+The traffic file's ``strategy`` names what is built, on one chip:
+``prediction`` (``PredictionExchange``, Algorithm 1: ``models`` stacked
+peers, with the file's ``period``, ``distill``, ``alpha`` and
+``compression``) or ``all_reduce`` (``AllReduce``, the paper's baseline:
+one model, no exchange and no distill term). Any other strategy is refused.
 """
 from __future__ import annotations
 
@@ -80,13 +86,16 @@ def build(cfg: Dict, traffic: Dict, layout: Dict) -> Program:
     model = build_model(mcfg)
     n = int(traffic["models"])
     name = traffic["strategy"]
-    if name != "prediction":
-        raise ValueError(f"strategy {name!r}: the harness drives the "
-                         "one-chip prediction exchange only")
-    codist = CodistConfig(
-        n_models=n, mode="predictions", period=int(traffic["period"]),
-        distill_loss=traffic["distill"], alpha0=float(traffic["alpha"]),
-        compression=traffic["compression"])
+    if name == "prediction":
+        codist = CodistConfig(
+            n_models=n, mode="predictions", period=int(traffic["period"]),
+            distill_loss=traffic["distill"], alpha0=float(traffic["alpha"]),
+            compression=traffic["compression"])
+    elif name == "all_reduce":
+        codist = CodistConfig(n_models=1)
+    else:
+        raise ValueError(f"strategy {name!r}: the harness builds "
+                         "'prediction' and 'all_reduce' only, on one chip")
     if float(traffic["weight_decay"]) != 0.0:
         raise ValueError("the first gradient is read from the momentum, "
                          "which needs weight_decay 0")

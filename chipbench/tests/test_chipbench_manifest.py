@@ -20,7 +20,8 @@ def test_finds_config_cell_traffic_limits_and_metric_by_name(bench):
     cfg = manifest.config(bench, cell["config"])
     assert cfg["hidden_size"] == 1024 and cfg["name"] == "qwen1.5-0.5b"
     assert manifest.traffic(cell["traffic"])["models"] == 2
-    assert set(manifest.limits(cell["name"])) == {"grad_gap", "change_gap"}
+    assert set(manifest.limits(cell["name"])) == {"loss_gap", "grad_gap",
+                                                  "change_gap"}
     assert callable(manifest.metric_reader("step_mfu"))
     assert hasattr(manifest.reference(cfg), "total_loss")
     with pytest.raises(manifest.ManifestError, match="no workload named"):

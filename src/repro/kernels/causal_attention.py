@@ -14,8 +14,10 @@ The query block is transposed once, so each product streams bq rows through
 a (hd, bk) weight tile. q is scaled by hd^-0.5 in fp32 and cast back; the
 scores and the online softmax are fp32; P is cast to the inputs' dtype for
 PV, which accumulates in fp32. It saves each query's logsumexp, (B, H, 1, S)
-fp32. The output and the logsumexp carry the checkpoint name ``RESIDUAL``,
-so a remat policy can keep them and not rerun the forward.
+fp32. Its inputs q, k and v, its output and the logsumexp carry the
+checkpoint name ``RESIDUAL``, so a remat policy can keep all the backward
+takes and rerun neither the kernel nor the projections, bias and RoPE that
+made its inputs.
 
 Backward, one kernel, grid (B, H, S/bk): its first key block transposes the
 head's Q and dO into VMEM and forms rowsum(dO * O) per query; each key block
@@ -46,8 +48,8 @@ MAX_SEQ = 8192                  # a head's K, V (forward) and Q, O, dO, dQ
                                 # (backward) stay in VMEM for the sequence
 MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
 VMEM_LIMIT = 64 * 2 ** 20
-RESIDUAL = "causal_attention_residual"   # the output and logsumexp the
-                                         # backward takes
+RESIDUAL = "causal_attention_residual"   # q, k, v, the output and the
+                                         # logsumexp: what the backward takes
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
@@ -267,6 +269,7 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, hd: int,
 
 def _vjp_fwd(q, k, v, hd, interpret):
     # named, so a remat policy may keep them and not rerun the forward
+    q, k, v = (checkpoint_name(x, RESIDUAL) for x in (q, k, v))
     o, lse = (checkpoint_name(x, RESIDUAL)
               for x in _fwd(q, k, v, hd=hd, interpret=interpret))
     return o, (q, k, v, o, lse)

@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
 from repro.kernels import causal_attention
@@ -33,10 +34,13 @@ from repro.models.moe import init_moe, moe_forward
 
 PyTree = Any
 
-# remat keeps only the values the fused attention kernels name as their
-# residuals (none on the dense route): everything else is recomputed
+# remat keeps what the fused attention kernels' backward takes (q, k, v, the
+# output and logsumexp, named by the kernels) and the residual stream after
+# such a mixer, so it reruns only the MLP's half of the layer. On the dense
+# route nothing is named and the whole layer is rerun from its input.
+MIXER_RESIDUAL = "mixer_residual"
 REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
-    causal_attention.RESIDUAL)
+    causal_attention.RESIDUAL, MIXER_RESIDUAL)
 
 
 # ----------------------------------------------------------------------------
@@ -110,6 +114,15 @@ def _sublayer_fwd(p: Dict, x: jax.Array, cfg: ModelConfig, mixer: str,
         else:  # rwkv time mix
             h, _ = rk.time_mix_forward(p["mix"], h, cfg)
     x = x + h
+    if mixer == "attn" and attn._takes_fused_core(cfg, True, x.shape[1]):
+        # the mixer's backward reruns nothing here, so remat keeps this
+        # residual and reruns only the MLP. JAX rounds a kept value to its
+        # dtype in the forward; rounding it here too makes the forward the
+        # same with or without remat, where XLA would keep excess precision
+        finfo = jnp.finfo(x.dtype)
+        x = checkpoint_name(jax.lax.reduce_precision(
+            x, exponent_bits=finfo.nexp, mantissa_bits=finfo.nmant),
+            MIXER_RESIDUAL)
     with jax.named_scope("mlp"):
         h = apply_norm(p["norm2"], x, cfg.norm_eps)
         if ffn == "moe":
@@ -137,8 +150,7 @@ def _scan_forward(layers: PyTree, x: jax.Array, cfg: ModelConfig,
 
     from repro.models.runtime_flags import scan_unroll
     if remat:
-        # the fused attention kernels' output and logsumexp stay, so remat
-        # reruns the projections but not the attention core
+        # on the fused attention route the backward reruns only the MLP
         body = jax.checkpoint(body, policy=REMAT_POLICY)
     with jax.named_scope("layers"):
         x, auxs = jax.lax.scan(body, x, layers, unroll=scan_unroll())

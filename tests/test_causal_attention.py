@@ -263,3 +263,72 @@ def test_dense_route_on_cpu_is_bit_identical_to_the_parent(kind):
         np.testing.assert_array_equal(np.asarray(cache[name], np.float32),
                                       np.asarray(want_cache[name],
                                                  np.float32))
+
+
+# ----------------------------------------------------------------------------
+# what remat keeps of the scanned layers
+# ----------------------------------------------------------------------------
+
+def _scan_residuals(monkeypatch, on: bool, cfg: ModelConfig, b: int, s: int):
+    """The shapes and dtypes the remat'd layer scan saves for the backward,
+    less the weights it takes as arguments and the constants."""
+    # private in JAX 0.9; the public print_saved_residuals prints these
+    from jax._src.ad_checkpoint import saved_residuals
+    from repro.models import transformer as T
+
+    _fused_route(monkeypatch, on)
+    layers = T.LM(cfg).init(jax.random.key(0))["layers"]
+    x = jax.random.normal(jax.random.key(1), (b, s, D)).astype(jnp.bfloat16)
+    pos = jnp.arange(s, dtype=jnp.int32)[None]
+    kept = saved_residuals(
+        lambda lp, x: T._scan_forward(lp, x, cfg, pos, remat=True)[0],
+        layers, x)
+    return sorted((a.shape, a.dtype) for a, why in kept
+                  if "argument" not in why and "constant" not in why)
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["fused", "dense"])
+def test_remat_keeps_the_attention_half_on_the_fused_route(monkeypatch, on):
+    """On the fused route the scan keeps each layer's input, the kernels'
+    q, k, v, o (sequence-minor) and logsumexp, and the residual stream
+    after the mixer: the backward reruns only the MLP. On the dense route
+    the mixer is rerun anyway, and only each layer's input is kept."""
+    h, kv, hd, b, s = 4, 2, 64, 2, 128
+    cfg = _cfg(h, kv, hd)
+    n = cfg.num_layers
+    stream = ((n, b, s, D), jnp.bfloat16)
+    want = [stream]                                            # the input
+    if on:
+        want = sorted([stream, stream,                         # + mid
+                       ((n, b, h * hd, s), jnp.bfloat16),      # q
+                       ((n, b, kv * hd, s), jnp.bfloat16),     # k
+                       ((n, b, kv * hd, s), jnp.bfloat16),     # v
+                       ((n, b, h * hd, s), jnp.bfloat16),      # o
+                       ((n, b, h, 1, s), jnp.float32)])        # lse
+    assert _scan_residuals(monkeypatch, on, cfg, b, s) == want
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["fused", "dense"])
+def test_remat_gradients_are_the_gradients_without_remat(monkeypatch, on):
+    """The values remat keeps are the ones the forward computed, so the
+    whole model's gradients do not move."""
+    from repro.models import transformer as T
+
+    _fused_route(monkeypatch, on)
+    cfg = _cfg(4, 2, 64)
+    model = T.LM(cfg)
+    params = model.init(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 128), 0,
+                                cfg.vocab_size)
+    ct = jax.random.normal(jax.random.key(2), (2, 128, cfg.padded_vocab))
+
+    def grads(remat):
+        def loss(p):
+            logits, aux = model.forward(p, {"tokens": tokens}, remat=remat)
+            return jnp.sum(logits.astype(jnp.float32) * ct) + aux
+        return jax.jit(jax.grad(loss))(params)
+
+    got, want = grads(True), grads(False)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        assert _rel(g, w, 1e-30) < 1e-6, jax.tree_util.keystr(path)

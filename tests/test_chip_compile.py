@@ -10,6 +10,7 @@ TPU compiler, and a machine that cannot describe it skips here.
 """
 from __future__ import annotations
 
+import collections
 import re
 
 import jax
@@ -27,6 +28,7 @@ H = KVH = CFG.num_heads
 HD = CFG.resolved_head_dim
 SLOTS, BS, NB, MB = 8, 16, 129, 8
 HBM_BYTES = 15.75 * 2 ** 30     # what the compiler reports a v5e can hold
+RESERVE_BYTES = 0.5 * 2 ** 30   # what the full-width step leaves of it
 
 
 @pytest.fixture(scope="module")
@@ -173,7 +175,7 @@ def test_paged_scatter_compiles(one_chip, pool_dtype):
 def test_full_width_codist_step_fits_one_chip(one_chip, monkeypatch):
     """24 layers, 464M parameters per peer, 2 peers, SGD with momentum,
     remat, fused losses and attention, state donated: the step must fit a
-    v5e's HBM."""
+    v5e's HBM with a reserve, with remat rerunning only the MLP."""
     from repro.kernels import ops
     from repro.models import build_model
     from repro.optim import make_optimizer
@@ -206,8 +208,15 @@ def test_full_width_codist_step_fits_one_chip(one_chip, monkeypatch):
     text = compiled.as_text()
     assert len(re.findall(r"%causal_attention_fwd[.\d]* = ", text)) == 1
     assert len(re.findall(r"%causal_attention_bwd[.\d]* = ", text)) == 1
+    # remat keeps q, k, v and the residual after attention: no projection
+    # is rerun, and the MLP's gate and up are
+    rerun = collections.Counter(
+        m.group(1) for m in re.finditer(
+            r" (?:dot|convolution)\(.*op_name=\"[^\"]*rematted_computation"
+            r"/(\w+)/", text))
+    assert rerun["attn"] == 0 and rerun["mlp"] > 0, rerun
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.alias_size_in_bytes > 0.9 * mem.output_size_in_bytes
-    assert used < HBM_BYTES, (used / 2 ** 30, mem)
+    assert used < HBM_BYTES - RESERVE_BYTES, (used / 2 ** 30, mem)
